@@ -28,11 +28,13 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.configs import SHAPES, get_config
+from repro.core.cost_model import V5E
 
-PEAK = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM = 16 * 2**30
+# the dry-run's target chip: a v5e (published peaks, one table)
+PEAK = V5E.peak_flops
+HBM_BW = V5E.hbm_bw
+ICI_BW = V5E.ici_bw
+HBM = V5E.hbm_bytes
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 OUT = os.path.join(os.path.dirname(__file__), "..", "artifacts")

@@ -6,6 +6,9 @@
   Eq 3-6    -> planner_quality            kernels -> grouped-kernel claim
   §Roofline -> roofline (reads artifacts/dryrun)   serve_trace -> §5.4 online
 
+A module that raises prints an ``<module>/ERROR`` row and the run exits 1
+after the remaining modules.
+
 ``--json`` additionally writes one ``BENCH_<module>.json`` artifact per
 module run ({row name -> us_per_call}) so the perf trajectory is tracked
 across PRs by diffing artifacts instead of scraping stdout.
@@ -45,6 +48,7 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs.log import get_logger
 
 log = get_logger("bench")
@@ -304,7 +308,9 @@ def main() -> None:
         sys.exit(compare(compare_dir, threshold, retain=retain, tag=tag,
                          blocking=blocking, baseline_tag=baseline_tag))
 
+    configure_compile_cache()
     print("name,us_per_call,derived")
+    failed: list[str] = []
     for name in MODULES:
         if only and name not in only:
             continue
@@ -318,6 +324,7 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         if as_json and rows:
             # no artifact for a module that errored before producing rows —
             # an empty BENCH_*.json would let CI's artifact check go green
@@ -335,6 +342,9 @@ def main() -> None:
                 json.dump(art, f, indent=2, sort_keys=True)
             log.info("wrote %s (%d rows)", path, len(art))
         log.info("%s done in %.1fs", name, time.time() - t0)
+    if failed:
+        log.error("modules that raised: %s", ",".join(failed))
+        sys.exit(1)
 
 
 if __name__ == "__main__":

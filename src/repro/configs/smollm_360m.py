@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M; hf].
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M; hf].
 
 Also the backbone used by the end-to-end training example (~360M params).
 """

@@ -22,12 +22,41 @@ from repro.core.task import HTask, ParallelismSpec, PEFTTask
 from repro.peft.methods import base_op_dims, supports_attention_prefix
 from repro.peft.methods import adapter_shared_params, adapter_sites
 
-# TPU v5e-class hardware constants (per chip) — also used by §Roofline.
-PEAK_FLOPS = 197e12       # bf16
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 50e9             # bytes/s/link
-VMEM_BYTES = 16 * 2**20
-HBM_BYTES = 16 * 2**30
+@dataclass(frozen=True)
+class DevicePeaks:
+    peak_flops: float  # bf16 FLOP/s
+    hbm_bw: float      # bytes/s
+    ici_bw: float      # bytes/s per inter-chip link
+    hbm_bytes: float
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect
+#: (4 links of 50 GB/s).
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(197e12, 819e9, 50e9, 16 * 2**30),
+}
+#: What CPU runs (tests, planning without a chip) price against.
+V5E = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_peaks() -> DevicePeaks:
+    """Peaks of the device this process computes on: the table entry for a
+    TPU's ``device_kind`` (an unknown kind is an error, not a default), and
+    the v5e entry on other backends."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return V5E
+    try:
+        return DEVICE_PEAKS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {dev.device_kind!r}; add "
+            "it to repro.core.cost_model.DEVICE_PEAKS with its source"
+        ) from None
 
 
 @dataclass(frozen=True)
@@ -42,11 +71,19 @@ class OpCost:
 
 @dataclass
 class HardwareProfile:
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    peak_flops: float = V5E.peak_flops
+    hbm_bw: float = V5E.hbm_bw
+    ici_bw: float = V5E.ici_bw
     util_x_half: float = 2.0e9  # FLOPs per op at 50% utilization
     calibration: Dict[str, float] = field(default_factory=dict)
+    hbm_bytes: float = V5E.hbm_bytes
+
+    @classmethod
+    def for_device(cls) -> "HardwareProfile":
+        """Analytic profile of the device this process computes on."""
+        p = device_peaks()
+        return cls(peak_flops=p.peak_flops, hbm_bw=p.hbm_bw, ici_bw=p.ici_bw,
+                   hbm_bytes=p.hbm_bytes)
 
     def utilization(self, flops: float) -> float:
         """Saturation curve: small ops underutilize the MXU (§2.2)."""
@@ -252,7 +289,11 @@ class CostModel:
             m_act += act * min(S, 1 + 1) + adapters  # <= S in-flight copies; 1F1B steady ~ S
         return (m_backbone + m_grad) / 1.0 + m_act * S + sum(shared.values())
 
-    def fits_memory(self, htasks: Sequence[HTask], budget: float = HBM_BYTES) -> bool:
+    def fits_memory(self, htasks: Sequence[HTask],
+                    budget: Optional[float] = None) -> bool:
+        """``budget`` defaults to the profile's HBM."""
+        if budget is None:
+            budget = self.hw.hbm_bytes
         return self.stage_memory(htasks) <= budget
 
     # -------------------------------------------------- decode-token term
@@ -352,8 +393,7 @@ def calibrate_profile(
         # raw analytic predictions: a bare profile with the fitted knee but
         # NO calibration entries (decode_scale would otherwise fall back to
         # the freshly-fitted __wall__ and fold it into the fit)
-        bare = HardwareProfile(out.peak_flops, out.hbm_bw, out.ici_bw,
-                               out.util_x_half, {})
+        bare = dataclasses.replace(out, calibration={})
         cm = CostModel(cfg, [], parallelism, bare)
         p = np.asarray([cm.decode_token_latency(int(r), int(max(ctx, 1)))
                         for r, ctx, _s in decode_samples], np.float64)
@@ -363,7 +403,7 @@ def calibrate_profile(
             out.calibrate("__decode__", float(p @ meas) / denom)
         return out
 
-    base = base_hw or HardwareProfile()
+    base = base_hw or HardwareProfile.for_device()
     if not samples:
         if not decode_samples:
             return base  # nothing to fit: identity, not a copy

@@ -16,7 +16,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.alignment import AlignmentPlan, align_tasks
-from repro.core.cost_model import CostModel, HBM_BYTES
+from repro.core.cost_model import CostModel
 from repro.core.task import HTask, ParallelismSpec, PEFTTask
 
 
@@ -54,7 +54,7 @@ def fuse_tasks(
     cost_model: CostModel,
     n_micro: int = 4,
     alignment_mode: str = "chunked",
-    memory_budget: float = HBM_BYTES,
+    memory_budget: Optional[float] = None,  # None: the profile's HBM
     max_htasks: Optional[int] = None,
 ) -> FusionResult:
     M = len(tasks)

@@ -18,7 +18,7 @@ from typing import Dict, List, Optional, Sequence
 
 from repro.configs import ArchConfig
 from repro.core.alignment import AlignmentPlan
-from repro.core.cost_model import CostModel, HardwareProfile, HBM_BYTES
+from repro.core.cost_model import CostModel, HardwareProfile
 from repro.core.fusion import FusionResult, fuse_tasks
 from repro.core.grouping import make_buckets
 from repro.core.pipeline_template import (
@@ -77,12 +77,15 @@ class ExecutionPlanner:
         cfg: ArchConfig,
         parallelism: ParallelismSpec,
         hw: Optional[HardwareProfile] = None,
-        memory_budget: float = HBM_BYTES,
+        memory_budget: Optional[float] = None,
     ):
+        """``hw`` defaults to the running device's profile and
+        ``memory_budget`` to that device's HBM."""
         self.cfg = cfg
         self.parallelism = parallelism
-        self.hw = hw or HardwareProfile()
-        self.memory_budget = memory_budget
+        self.hw = hw or HardwareProfile.for_device()
+        self.memory_budget = (self.hw.hbm_bytes if memory_budget is None
+                              else memory_budget)
 
     def cost_model(self, tasks: Sequence[PEFTTask],
                    enable_orchestration: bool = True) -> CostModel:

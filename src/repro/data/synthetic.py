@@ -7,6 +7,7 @@ so runs are reproducible.
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -63,8 +64,10 @@ def token_stream(task_id: str, vocab: int, seed: int = 0):
     Learnable structure: a per-task affine recurrence with occasional noise
     tokens — next-token loss decreases under training (the task's "domain"),
     while tasks differ (per-task multiplier), so per-tenant adapter progress
-    is observable and distinguishable."""
-    h = abs(hash((task_id, seed))) % (2**31)
+    is observable and distinguishable.  The stream is seeded from a CRC of
+    ``task_id:seed``, not ``hash()`` (salted per process), so every process
+    trains on the same tokens."""
+    h = zlib.crc32(f"{task_id}:{seed}".encode()) % (2**31)
     rng = np.random.RandomState(h)
     v = max(vocab - 2, 2)
     a = 3 + 2 * (h % 11)      # per-task odd multiplier

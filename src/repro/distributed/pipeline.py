@@ -125,9 +125,7 @@ def pipeline_shard_map(
         return jax.lax.psum(outs * is_last, stage_axis)
 
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    from repro.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),

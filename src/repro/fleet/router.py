@@ -32,7 +32,9 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field, replace
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.cluster.simulator import ClusterSim, TaskArrival
 from repro.core.task import PEFTTask
@@ -175,10 +177,14 @@ class FleetRouter:
         telemetry: Optional[TelemetryRegistry] = None,
         migration: Optional[MigrationProtocol] = None,
         oracle: bool = True,
+        devices: Optional[Sequence[jax.Device]] = None,
     ):
         if policy not in ("fcfs", "best_fit", "backbone_affine"):
             raise ValueError(policy)
         self.factory = factory
+        # instance i computes on devices[i % len(devices)]: one chip per
+        # instance on a multi-chip host; [d] stacks every instance on d
+        self.devices = list(devices) if devices else jax.devices()
         self.policy = policy
         self.max_queue = max_queue
         self.backbone = backbone
@@ -220,7 +226,10 @@ class FleetRouter:
         """Provision one instance (and mirror it into the oracle)."""
         iid = self._next_iid
         self._next_iid += 1
-        svc = self.factory(iid)
+        device = self.devices[iid % len(self.devices)]
+        with jax.default_device(device):
+            svc = self.factory(iid)
+        svc.device = device
         # per-instance pinned label + Eq. 5 backbone footprint: the service
         # config decides both (an int8 backbone is a different label AND a
         # smaller resident copy than fp32 of the same arch)

@@ -9,6 +9,11 @@ output, running max and partial denominator — in parallel across a
 online-softmax combine in plain XLA (the reduction is tiny:
 ``[B, Hkv, n_splits, G]``).
 
+The kernel reads a head-major ``[B, Hkv, Smax, dh]`` view of the cache, so
+each split is a ``(split, dh)`` tile (the TPU's (8, 128) block rule); the
+per-row window bounds ride scalar prefetch.  The wrapper transposes the
+model's ``[B, Smax, Hkv, dh]`` cache into that view on every call.
+
 Every KV element is read exactly once per decoded token, and splits that
 fall entirely outside a row's window contribute ``(m=-1e30, l=0)`` which
 vanish in the combine, so masked prefix padding costs bandwidth but never
@@ -24,56 +29,49 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import SUBLANE, fit_block
 
 NEG_INF = -1e30
 
 
-def _fit_split(smax: int, want: int) -> int:
-    """Largest divisor of smax that is <= want (want >= 1)."""
-    want = max(1, min(want, smax))
-    for cand in range(want, 0, -1):
-        if smax % cand == 0:
-            return cand
-    return smax
-
-
 def _stage1_kernel(
+    len_ref,     # [B] int32 scalar prefetch: window end per row
+    start_ref,   # [B] int32 scalar prefetch: window start per row
     q_ref,       # [1, 1, G, dh]
-    k_ref,       # [1, split, 1, dh]
-    v_ref,       # [1, split, 1, dh]
-    len_ref,     # [1, 1] int32
-    start_ref,   # [1, 1] int32
+    k_ref,       # [1, 1, split, dh]
+    v_ref,       # [1, 1, split, dh]
     o_ref,       # [1, 1, 1, G, dh] f32 partial out
-    m_ref,       # [1, 1, 1, G]     f32 running max
-    l_ref,       # [1, 1, 1, G]     f32 partial denominator
+    m_ref,       # [1, 1, 1, G, 1]  f32 running max
+    l_ref,       # [1, 1, 1, G, 1]  f32 partial denominator
     *,
+    hkv: int,
     split: int,
     g: int,
     scale: float,
 ):
+    b = pl.program_id(0) // hkv
     s_idx = pl.program_id(1)
-    q = q_ref[0, 0, :, :].astype(jnp.float32)      # [G, dh]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # [split, dh]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)            # [G, dh]
+    k = k_ref[0, 0].astype(jnp.float32)            # [split, dh]
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                      # [G, split]
-    lo = start_ref[0, 0]
-    hi = len_ref[0, 0]
     pos = s_idx * split + jax.lax.broadcasted_iota(jnp.int32, (g, split), 1)
-    mask = (pos >= lo) & (pos < hi)
+    mask = (pos >= start_ref[b]) & (pos < len_ref[b])
     s = jnp.where(mask, s, NEG_INF)
-    m = s.max(axis=-1)                             # [G]
+    m = s.max(axis=-1, keepdims=True)              # [G, 1]
     # re-mask after exp: a fully-masked split has m == NEG_INF and would
     # otherwise produce exp(0) == 1 on every masked column
-    p = jnp.where(mask, jnp.exp(s - m[:, None]), 0.0)
-    l = p.sum(axis=-1)                             # [G]
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
     acc = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                              # [G, dh]
-    o_ref[0, 0, 0, :, :] = acc
-    m_ref[0, 0, 0, :] = m
-    l_ref[0, 0, 0, :] = l
+    o_ref[0, 0, 0] = acc
+    m_ref[0, 0, 0] = m
+    l_ref[0, 0, 0] = p.sum(axis=-1, keepdims=True)
 
 
 def decode_attention_pallas(
@@ -91,46 +89,58 @@ def decode_attention_pallas(
     G = H // Hkv
     scale = 1.0 / np.sqrt(dh)
 
-    split = _fit_split(Smax, split_k)
+    split = fit_block(Smax, split_k, SUBLANE)
     n_splits = Smax // split
 
-    q5 = q.reshape(B, Hkv, G, dh)
+    q4 = q.reshape(B, Hkv, G, dh)
+    # head-major cache: each split is a (split, dh) tile of one kv head
+    k4 = k_cache.transpose(0, 2, 1, 3)
+    v4 = v_cache.transpose(0, 2, 1, 3)
     len_b = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
     if cache_start is None:
         start_b = jnp.zeros((B,), jnp.int32)
     else:
         start_b = jnp.broadcast_to(jnp.asarray(cache_start, jnp.int32).reshape(-1), (B,))
-    len2 = len_b[:, None]      # [B, 1]
-    start2 = start_b[:, None]
 
-    kernel = functools.partial(_stage1_kernel, split=split, g=G, scale=scale)
+    def head(bh, s, *_):
+        return (bh // Hkv, bh % Hkv, 0, 0)
+
+    def kv(bh, s, *_):
+        return (bh // Hkv, bh % Hkv, s, 0)
+
+    def part(bh, s, *_):
+        return (bh // Hkv, bh % Hkv, s, 0, 0)
+
+    kernel = functools.partial(_stage1_kernel, hkv=Hkv, split=split, g=G,
+                               scale=scale)
     o_part, m_part, l_part = pl.pallas_call(
         kernel,
-        grid=(B * Hkv, n_splits),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda bh, s: (bh // Hkv, bh % Hkv, 0, 0)),
-            pl.BlockSpec((1, split, 1, dh), lambda bh, s: (bh // Hkv, s, bh % Hkv, 0)),
-            pl.BlockSpec((1, split, 1, dh), lambda bh, s: (bh // Hkv, s, bh % Hkv, 0)),
-            pl.BlockSpec((1, 1), lambda bh, s: (bh // Hkv, 0)),
-            pl.BlockSpec((1, 1), lambda bh, s: (bh // Hkv, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, dh), lambda bh, s: (bh // Hkv, bh % Hkv, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda bh, s: (bh // Hkv, bh % Hkv, s, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda bh, s: (bh // Hkv, bh % Hkv, s, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * Hkv, n_splits),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, dh), head),
+                pl.BlockSpec((1, 1, split, dh), kv),
+                pl.BlockSpec((1, 1, split, dh), kv),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, 1, G, dh), part),
+                pl.BlockSpec((1, 1, 1, G, 1), part),
+                pl.BlockSpec((1, 1, 1, G, 1), part),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, n_splits, G, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q5, k_cache, v_cache, len2, start2)
+    )(len_b, start_b, q4, k4, v4)
 
     # stage 2: online-softmax combine across splits (tiny reduction)
-    m_star = m_part.max(axis=2)                          # [B, Hkv, G]
-    alpha = jnp.exp(m_part - m_star[:, :, None, :])      # [B, Hkv, n_splits, G]
-    l_star = (l_part * alpha).sum(axis=2)                # [B, Hkv, G]
-    out = (o_part * alpha[..., None]).sum(axis=2)        # [B, Hkv, G, dh]
-    out = out / jnp.maximum(l_star, 1e-20)[..., None]
+    m_star = m_part.max(axis=2, keepdims=True)           # [B, Hkv, 1, G, 1]
+    alpha = jnp.exp(m_part - m_star)                     # [B, Hkv, n, G, 1]
+    l_star = (l_part * alpha).sum(axis=2)                # [B, Hkv, G, 1]
+    out = (o_part * alpha).sum(axis=2)                   # [B, Hkv, G, dh]
+    out = out / jnp.maximum(l_star, 1e-20)
     return out.reshape(B, 1, H, dh).astype(q.dtype)

@@ -45,17 +45,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import LANE, fit_block
+
 
 def _fwd_kernel(
     # scalar prefetch
     block_task_ref,  # [n_m] int32
     scale_ref,       # [T] f32
     # inputs
-    x_ref,           # [block_m, block_k]
+    x_ref,           # [1, block_m, block_k]
     a_ref,           # [1, block_k, r]
     b_ref,           # [1, r, d_out]
     # outputs
-    o_ref,           # [block_m, d_out]
+    o_ref,           # [1, block_m, d_out]
     *rest,           # (h_out_ref?, h_ref scratch)
     n_k: int,
     save_h: bool,
@@ -69,7 +71,7 @@ def _fwd_kernel(
         h_ref[...] = jnp.zeros_like(h_ref)
 
     h_ref[...] += jax.lax.dot_general(
-        x_ref[...], a_ref[0],
+        x_ref[0], a_ref[0],
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -83,9 +85,9 @@ def _fwd_kernel(
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        o_ref[...] = (y * gate).astype(o_ref.dtype)
+        o_ref[0] = (y * gate).astype(o_ref.dtype)
         if save_h:
-            rest[0][...] = h_ref[...]
+            rest[0][0] = h_ref[...]
 
 
 def _bwd_kernel(
@@ -93,13 +95,13 @@ def _bwd_kernel(
     block_task_ref,  # [n_m] int32
     scale_ref,       # [T] f32
     # inputs
-    x_ref,           # [block_m, block_k]
-    g_ref,           # [block_m, d_out]   (dy)
-    h_ref,           # [block_m, r] f32   (saved rank activations)
+    x_ref,           # [1, block_m, block_k]
+    g_ref,           # [1, block_m, d_out]   (dy)
+    h_ref,           # [1, block_m, r] f32   (saved rank activations)
     a_ref,           # [1, block_k, r]
     b_ref,           # [1, r, d_out]
     # outputs
-    dx_ref,          # [block_m, block_k]
+    dx_ref,          # [1, block_m, block_k]
     dap_ref,         # [1, block_k, r]    per-block dA partial
     dmp_ref,         # [1, r, d_out]      per-block unscaled dB partial
     # scratch
@@ -115,7 +117,7 @@ def _bwd_kernel(
         t = block_task_ref[i]
         valid = jnp.where(t >= 0, 1.0, 0.0)
         gate = valid * scale_ref[jnp.maximum(t, 0)]
-        g = g_ref[...].astype(jnp.float32)
+        g = g_ref[0].astype(jnp.float32)
         # dh = (g @ B^T) * scale — gated to zero for adapter-less blocks
         dh_ref[...] = jax.lax.dot_general(
             g, b_ref[0].astype(jnp.float32),
@@ -124,23 +126,34 @@ def _bwd_kernel(
         ) * gate
         # unscaled dB partial: h^T @ g (valid-gated; scale applied outside)
         dmp_ref[0] = jax.lax.dot_general(
-            h_ref[...], g,
+            h_ref[0], g,
             (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * valid
 
     # dX tile: dh @ A^T over this d_in tile
-    dx_ref[...] = jax.lax.dot_general(
+    dx_ref[0] = jax.lax.dot_general(
         dh_ref[...], a_ref[0].astype(jnp.float32),
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ).astype(dx_ref.dtype)
     # per-block dA partial for this d_in tile: x^T @ dh
     dap_ref[0] = jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), dh_ref[...],
+        x_ref[0].astype(jnp.float32), dh_ref[...],
         (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+
+
+# Row blocks ride a leading [n_m] axis, [n_m, block_m, d]: a block's last
+# two dims are then (block_m, d-tile) with block_m the whole array dim, legal
+# on the TPU at any block_m — decode's one-row blocks included.
+def _row_tile(i, k, bt, sc):
+    return (i, 0, k)
+
+
+def _row_block(i, k, bt, sc):
+    return (i, 0, 0)
 
 
 def _fwd_call(x, a, b, row_task, scale, block_m, block_k, interpret, save_h):
@@ -151,17 +164,17 @@ def _fwd_call(x, a, b, row_task, scale, block_m, block_k, interpret, save_h):
 
     block_task = row_task[:: block_m].astype(jnp.int32)  # [n_m] (block-constant)
 
-    out_shape = [jax.ShapeDtypeStruct((M, d_out), x.dtype)]
-    out_specs = [pl.BlockSpec((block_m, d_out), lambda i, k, bt, sc: (i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((n_m, block_m, d_out), x.dtype)]
+    out_specs = [pl.BlockSpec((1, block_m, d_out), _row_block)]
     if save_h:
-        out_shape.append(jax.ShapeDtypeStruct((M, r), jnp.float32))
-        out_specs.append(pl.BlockSpec((block_m, r), lambda i, k, bt, sc: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((n_m, block_m, r), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, block_m, r), _row_block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_m, n_k),
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, k, bt, sc: (i, k)),
+            pl.BlockSpec((1, block_m, block_k), _row_tile),
             pl.BlockSpec(
                 (1, block_k, r), lambda i, k, bt, sc: (jnp.maximum(bt[i], 0), k, 0)
             ),
@@ -178,8 +191,10 @@ def _fwd_call(x, a, b, row_task, scale, block_m, block_k, interpret, save_h):
         out_shape=out_shape,
         interpret=interpret,
     )
-    out = fn(block_task, scale.astype(jnp.float32), x, a, b)
-    return out if save_h else out[0]
+    out = fn(block_task, scale.astype(jnp.float32),
+             x.reshape(n_m, block_m, d_in), a, b)
+    y = out[0].reshape(M, d_out)
+    return (y, out[1]) if save_h else y
 
 
 def _bwd_call(x, a, b, row_task, scale, h, g, block_m, block_k, interpret):
@@ -193,9 +208,9 @@ def _bwd_call(x, a, b, row_task, scale, h, g, block_m, block_k, interpret):
         num_scalar_prefetch=2,
         grid=(n_m, n_k),
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, k, bt, sc: (i, k)),
-            pl.BlockSpec((block_m, d_out), lambda i, k, bt, sc: (i, 0)),
-            pl.BlockSpec((block_m, r), lambda i, k, bt, sc: (i, 0)),
+            pl.BlockSpec((1, block_m, block_k), _row_tile),
+            pl.BlockSpec((1, block_m, d_out), _row_block),
+            pl.BlockSpec((1, block_m, r), _row_block),
             pl.BlockSpec(
                 (1, block_k, r), lambda i, k, bt, sc: (jnp.maximum(bt[i], 0), k, 0)
             ),
@@ -204,7 +219,7 @@ def _bwd_call(x, a, b, row_task, scale, h, g, block_m, block_k, interpret):
             ),
         ],
         out_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, k, bt, sc: (i, k)),
+            pl.BlockSpec((1, block_m, block_k), _row_tile),
             pl.BlockSpec((1, block_k, r), lambda i, k, bt, sc: (i, k, 0)),
             pl.BlockSpec((1, r, d_out), lambda i, k, bt, sc: (i, 0, 0)),
         ],
@@ -214,13 +229,16 @@ def _bwd_call(x, a, b, row_task, scale, h, g, block_m, block_k, interpret):
         functools.partial(_bwd_kernel, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((M, d_in), x.dtype),
+            jax.ShapeDtypeStruct((n_m, block_m, d_in), x.dtype),
             jax.ShapeDtypeStruct((n_m, d_in, r), jnp.float32),
             jax.ShapeDtypeStruct((n_m, r, d_out), jnp.float32),
         ],
         interpret=interpret,
     )
-    dx, da_p, dm_p = fn(block_task, scale.astype(jnp.float32), x, g, h, a, b)
+    dx, da_p, dm_p = fn(block_task, scale.astype(jnp.float32),
+                        x.reshape(n_m, block_m, d_in),
+                        g.reshape(n_m, block_m, d_out), h, a, b)
+    dx = dx.reshape(M, d_in)
 
     # Per-task reduction of the per-block partials (one scatter-add each).
     slots = jnp.maximum(block_task, 0)
@@ -267,8 +285,10 @@ def grouped_lora_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     M, d_in = x.shape
+    # block_m is the caller's (it keeps row_task block-constant); d_in tiles
+    # are lane blocks of x and dx, so a multiple of 128 or the whole d_in
     block_m = math.gcd(M, block_m)
-    block_k = math.gcd(d_in, block_k)
+    block_k = fit_block(d_in, block_k, LANE)
     return _grouped_lora(
         x, a, b, row_task.astype(jnp.int32), scale, block_m, block_k, interpret
     )

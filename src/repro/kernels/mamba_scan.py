@@ -11,6 +11,12 @@ state + MXU tiles.
 Grid: (B*H, n_chunks); chunk dim innermost so the [dk, dv] f32 state scratch
 persists across chunks of one (batch, head) program.
 
+Layout: the kernels see head-major ``[B, H, S, d]`` operands (the wrapper
+transposes the model's ``[B, S, H, d]``), so each chunk is a ``(Q, d)``
+tile; the per-position log-decay, log-input and reset rows enter as
+``(Q, 1)`` columns.  That is the TPU's (8, 128) block rule: the last two
+block dims are sublane-aligned or the whole array dim.
+
 The op is differentiable via ``jax.custom_vjp``.  The forward under autodiff
 additionally spills the per-chunk ENTRY states H_in ([B*H, n, dk, dv] f32 —
 one [dk, dv] tile per chunk, tiny next to q/k/v), so the backward never
@@ -59,29 +65,58 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gates(r_ref, chunk: int, masked: bool):
-    """Within-chunk reset-count gates: (pair [Q,Q], entry [Q], exit [Q],
-    carry scalar) — all 1.0 when the op runs without resets."""
+def _chunk_terms(la, li, r, chunk: int, masked: bool):
+    """Per-chunk decay terms from (Q, 1) columns of log-decay, log-input and
+    reset rows.  Prefix sums and the column -> row flips are masked
+    reductions over a (Q, Q) tile (2D VPU work), so every vector the
+    kernels touch is a (Q, 1) column or a (1, Q) row.
+
+    Returns cum (col, row), gain (col, row), the chunk total (1, 1) and the
+    within-chunk reset-count gates: pair [Q, Q], entry [Q, 1], exit [Q, 1]
+    and carry (1, 1) — all 1.0 when the op runs without resets."""
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = (ii >= jj).astype(jnp.float32)   # [i, j] = j <= i
+    triu = (ii <= jj).astype(jnp.float32)  # [i, j] = i <= j
+    eye = (ii == jj).astype(jnp.float32)
+
+    def row(col):
+        return (eye * col).sum(axis=0, keepdims=True)
+
+    la_row = row(la)
+    cum_col = (tri * la_row).sum(axis=1, keepdims=True)   # inclusive cumsum
+    cum_row = (triu * la).sum(axis=0, keepdims=True)
+    total = la.sum(axis=0, keepdims=True)                 # (1, 1)
+    gain_col = jnp.exp(li)
+    gain_row = row(gain_col)
     if not masked:
-        one = jnp.ones((chunk,), jnp.float32)
-        return jnp.ones((chunk, chunk), jnp.float32), one, one, 1.0
-    seg = jnp.cumsum(r_ref[0, :])  # [Q] inclusive reset count
-    pair = (seg[:, None] == seg[None, :]).astype(jnp.float32)
-    entry = (seg == 0).astype(jnp.float32)        # H_in reaches these rows
-    exit_ = (seg == seg[-1]).astype(jnp.float32)  # these rows feed H_out
-    carry = (seg[-1] == 0).astype(jnp.float32)    # H_in survives the chunk
-    return pair, entry, exit_, carry
+        return (cum_col, cum_row, gain_col, gain_row, total, tri,
+                1.0, 1.0, 1.0, 1.0)
+    seg_col = (tri * row(r)).sum(axis=1, keepdims=True)   # inclusive count
+    seg_row = (triu * r).sum(axis=0, keepdims=True)
+    n_res = r.sum(axis=0, keepdims=True)                  # (1, 1)
+    pair = (seg_col == seg_row).astype(jnp.float32)
+    entry = (seg_col == 0).astype(jnp.float32)            # H_in reaches these
+    exit_ = (seg_col == n_res).astype(jnp.float32)        # these feed H_out
+    carry = (n_res == 0).astype(jnp.float32)              # H_in survives
+    return (cum_col, cum_row, gain_col, gain_row, total, tri,
+            pair, entry, exit_, carry)
+
+
+def _decay(cum_col, cum_row, gain_row, tri, pair):
+    # dec[i, j] = exp(cum_i - cum_j) * gain_j for j <= i (within a segment)
+    return jnp.exp((cum_col - cum_row) * tri) * tri * gain_row * pair
 
 
 def _kernel(
-    q_ref,   # [1, Q, 1, dk]
-    k_ref,   # [1, Q, 1, dk]
-    v_ref,   # [1, Q, 1, dv]
-    la_ref,  # [1, Q, 1]
-    li_ref,  # [1, Q, 1]
-    r_ref,   # [1, Q] int32 reset rows
+    q_ref,   # [1, 1, Q, dk]
+    k_ref,   # [1, 1, Q, dk]
+    v_ref,   # [1, 1, Q, dv]
+    la_ref,  # [1, 1, Q, 1]
+    li_ref,  # [1, 1, Q, 1]
+    r_ref,   # [1, Q, 1] f32 reset rows
     h0_ref,  # [1, 1, dk, dv] initial state
-    y_ref,   # [1, Q, 1, dv]
+    y_ref,   # [1, 1, Q, dv]
     hout_ref,  # [1, 1, dk, dv] final state out
     *rest,   # (hin_ref? [1, 1, dk, dv], h_ref scratch [dk, dv] f32)
     n_chunks: int,
@@ -100,33 +135,26 @@ def _kernel(
         # entry state of THIS chunk — the backward's inter-chunk residual
         rest[0][0, 0] = h_ref[...]
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # [Q, dk]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # [Q, dv]
-    la = la_ref[0, :, 0]
-    li = li_ref[0, :, 0]
-    pair, entry, exit_, carry = _gates(r_ref, chunk, masked)
+    q = q_ref[0, 0].astype(jnp.float32)  # [Q, dk]
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)  # [Q, dv]
+    (cum, cum_row, gain, gain_row, total, tri,
+     pair, entry, exit_, carry) = _chunk_terms(
+        la_ref[0, 0], li_ref[0, 0], r_ref[0], chunk, masked)
 
-    cum = jnp.cumsum(la)  # [Q]
-    gain = jnp.exp(li)
-    tri = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
-    dec = jnp.exp((cum[:, None] - cum[None, :]) * tri) * tri * gain[None, :]
-    if masked:
-        dec = dec * pair
+    dec = _decay(cum, cum_row, gain_row, tri, pair)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     y_intra = jax.lax.dot_general(s * dec, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    qd = q * (jnp.exp(cum) * entry)[:, None]
+    qd = q * (jnp.exp(cum) * entry)
     y_inter = jax.lax.dot_general(qd, h_ref[...], (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    total = cum[-1]
-    w = jnp.exp(total - cum) * gain * exit_  # [Q]
-    kd = k * w[:, None]
+    w = jnp.exp(total - cum) * gain * exit_  # [Q, 1]
     h_ref[...] = (jnp.exp(total) * carry) * h_ref[...] + jax.lax.dot_general(
-        kd, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        k * w, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     @pl.when(j == n_chunks - 1)
     def _emit():
@@ -134,10 +162,10 @@ def _kernel(
 
 
 def _bwd_state_kernel(
-    q_ref,     # [1, Q, 1, dk]  (chunk n-1-j: reversed index maps)
-    dy_ref,    # [1, Q, 1, dv]
-    la_ref,    # [1, Q, 1]
-    r_ref,     # [1, Q] int32
+    q_ref,     # [1, 1, Q, dk]  (chunk n-1-j: reversed index maps)
+    dy_ref,    # [1, 1, Q, dv]
+    la_ref,    # [1, 1, Q, 1]
+    r_ref,     # [1, Q, 1] f32
     dhf_ref,   # [1, 1, dk, dv] final-state cotangent
     gexit_ref,  # [1, 1, dk, dv] chunk-exit adjoint out
     dh0_ref,   # [1, 1, dk, dv] initial-state cotangent out
@@ -156,14 +184,14 @@ def _bwd_state_kernel(
     # adjoint at THIS chunk's exit — consumed by the block-product kernel
     gexit_ref[0, 0] = g_ref[...]
 
-    la = la_ref[0, :, 0]
-    _, entry, _, carry = _gates(r_ref, chunk, masked)
-    cum = jnp.cumsum(la)  # [Q]
-    qd = q_ref[0, :, 0, :].astype(jnp.float32) * (jnp.exp(cum) * entry)[:, None]
-    dy = dy_ref[0, :, 0, :].astype(jnp.float32)
+    la = la_ref[0, 0]
+    cum, _, _, _, total, _, _, entry, _, carry = _chunk_terms(
+        la, jnp.zeros_like(la), r_ref[0], chunk, masked)
+    qd = q_ref[0, 0].astype(jnp.float32) * (jnp.exp(cum) * entry)
+    dy = dy_ref[0, 0].astype(jnp.float32)
     # G_exit(c-1) = e^{total} G_exit(c) + Qd^T dY  (reverse decay-cumsum);
     # a reset inside the chunk cuts both paths back to the entry state
-    g_ref[...] = (jnp.exp(cum[-1]) * carry) * g_ref[...] + jax.lax.dot_general(
+    g_ref[...] = (jnp.exp(total) * carry) * g_ref[...] + jax.lax.dot_general(
         qd, dy, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
@@ -173,39 +201,34 @@ def _bwd_state_kernel(
 
 
 def _bwd_chunk_kernel(
-    q_ref,     # [1, Q, 1, dk]
-    k_ref,     # [1, Q, 1, dk]
-    v_ref,     # [1, Q, 1, dv]
-    la_ref,    # [1, Q, 1]
-    li_ref,    # [1, Q, 1]
-    r_ref,     # [1, Q] int32
-    dy_ref,    # [1, Q, 1, dv]
+    q_ref,     # [1, 1, Q, dk]
+    k_ref,     # [1, 1, Q, dk]
+    v_ref,     # [1, 1, Q, dv]
+    la_ref,    # [1, 1, Q, 1]
+    li_ref,    # [1, 1, Q, 1]
+    r_ref,     # [1, Q, 1] f32
+    dy_ref,    # [1, 1, Q, dv]
     hin_ref,   # [1, 1, dk, dv] chunk ENTRY state (saved by the forward)
     gexit_ref,  # [1, 1, dk, dv] chunk EXIT adjoint (reverse-scan kernel)
-    dq_ref,    # [1, Q, 1, dk]
-    dk_ref,    # [1, Q, 1, dk]
-    dv_ref,    # [1, Q, 1, dv]
-    dcum_ref,  # [1, Q, 1]  q.dq - k.dk rows (decay cotangent, pre-cumsum)
-    dli_ref,   # [1, Q, 1]  k.dk rows (input-gate cotangent)
+    dq_ref,    # [1, 1, Q, dk]
+    dk_ref,    # [1, 1, Q, dk]
+    dv_ref,    # [1, 1, Q, dv]
+    dcum_ref,  # [1, 1, Q, 1]  q.dq - k.dk rows (decay cotangent, pre-cumsum)
+    dli_ref,   # [1, 1, Q, 1]  k.dk rows (input-gate cotangent)
     *,
     chunk: int,
     masked: bool,
 ):
-    q = q_ref[0, :, 0, :].astype(jnp.float32)   # [Q, dk]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)   # [Q, dv]
-    dy = dy_ref[0, :, 0, :].astype(jnp.float32)
-    la = la_ref[0, :, 0]
-    li = li_ref[0, :, 0]
-    pair, entry, exit_, _ = _gates(r_ref, chunk, masked)
+    q = q_ref[0, 0].astype(jnp.float32)   # [Q, dk]
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)   # [Q, dv]
+    dy = dy_ref[0, 0].astype(jnp.float32)
+    (cum, cum_row, gain, gain_row, total, tri,
+     pair, entry, exit_, _) = _chunk_terms(
+        la_ref[0, 0], li_ref[0, 0], r_ref[0], chunk, masked)
 
-    cum = jnp.cumsum(la)
-    gain = jnp.exp(li)
-    tri = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
-    dec = jnp.exp((cum[:, None] - cum[None, :]) * tri) * tri * gain[None, :]
-    if masked:
-        dec = dec * pair
-    w = jnp.exp(cum[-1] - cum) * gain * exit_  # [Q]
+    dec = _decay(cum, cum_row, gain_row, tri, pair)
+    w = jnp.exp(total - cum) * gain * exit_  # [Q, 1]
     hin = hin_ref[0, 0]    # [dk, dv]
     gex = gexit_ref[0, 0]  # [dk, dv]
 
@@ -218,56 +241,63 @@ def _bwd_chunk_kernel(
     # dq_i = sum_{j<=i} dec[i,j] (dy_i.v_j) k_j + e^{cum_i} H_in dy_i
     dq = jax.lax.dot_general(p, k, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    dq += (jnp.exp(cum) * entry)[:, None] * jax.lax.dot_general(
+    dq += (jnp.exp(cum) * entry) * jax.lax.dot_general(
         dy, hin, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     # dk_t = sum_{i>=t} dec[i,t] (dy_i.v_t) q_i + w_t G_exit v_t
     dk = jax.lax.dot_general(p, q, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    dk += w[:, None] * jax.lax.dot_general(
+    dk += w * jax.lax.dot_general(
         v, gex, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     # dv_t = sum_{i>=t} dec[i,t] (q_i.k_t) dy_i + w_t G_exit^T k_t
     dv = jax.lax.dot_general(s * dec, dy, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    dv += w[:, None] * jax.lax.dot_general(
+    dv += w * jax.lax.dot_general(
         k, gex, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    dq_ref[0, :, 0, :] = dq.astype(dq_ref.dtype)
-    dk_ref[0, :, 0, :] = dk.astype(dk_ref.dtype)
-    dv_ref[0, :, 0, :] = dv.astype(dv_ref.dtype)
-    kdk = (k * dk).sum(axis=1)
-    dcum_ref[0, :, 0] = (q * dq).sum(axis=1) - kdk
-    dli_ref[0, :, 0] = kdk
+    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    kdk = (k * dk).sum(axis=1, keepdims=True)
+    dcum_ref[0, 0] = (q * dq).sum(axis=1, keepdims=True) - kdk
+    dli_ref[0, 0] = kdk
 
 
-def _maps(H: int, n: int):
-    def xmap(bh, j):
-        return (bh // H, j, bh % H, 0)
+def _maps(H: int, n: int, *, reverse: bool = False):
+    """Index maps over the (B*H, n) grid; ``reverse`` visits chunks
+    last-to-first."""
 
-    def gmap(bh, j):
-        return (bh // H, j, bh % H)
+    def c(j):
+        return n - 1 - j if reverse else j
 
-    def rmap(bh, j):  # per-batch reset rows [B, S]
-        return (bh // H, j)
+    def xmap(bh, j):  # [B, H, S, d] and [B, H, S, 1]
+        return (bh // H, bh % H, c(j), 0)
+
+    def rmap(bh, j):  # per-batch reset rows [B, S, 1]
+        return (bh // H, c(j), 0)
 
     def smap(bh, j):
         return (bh // H, bh % H, 0, 0)
 
     def cmap(bh, j):  # per-chunk [dk, dv] tiles, [B*H, n, dk, dv] layout
-        return (bh, j, 0, 0)
+        return (bh, c(j), 0, 0)
 
-    return xmap, gmap, rmap, smap, cmap
+    return xmap, rmap, smap, cmap
+
+
+def _col(x):
+    return x[..., None]
 
 
 def _fwd_call(q, k, v, la, li, r, h0, chunk, interpret, masked, save_states):
-    B, S, H, dk = q.shape
+    B, H, S, dk = q.shape
     dv = v.shape[-1]
     Q = chunk
     n = S // Q
     grid = (B * H, n)
-    xmap, gmap, rmap, smap, cmap = _maps(H, n)
+    xmap, rmap, smap, cmap = _maps(H, n)
 
     out_specs = [
-        pl.BlockSpec((1, Q, 1, dv), xmap),
+        pl.BlockSpec((1, 1, Q, dv), xmap),
         pl.BlockSpec((1, 1, dk, dv), smap),
     ]
     out_shape = [
@@ -283,68 +313,60 @@ def _fwd_call(q, k, v, la, li, r, h0, chunk, interpret, masked, save_states):
                           save_states=save_states, masked=masked),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dv), xmap),
-            pl.BlockSpec((1, Q, 1), gmap),
-            pl.BlockSpec((1, Q, 1), gmap),
-            pl.BlockSpec((1, Q), rmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dv), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
+            pl.BlockSpec((1, Q, 1), rmap),
             pl.BlockSpec((1, 1, dk, dv), smap),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, la, li, r, h0)
+    )(q, k, v, _col(la), _col(li), _col(r.astype(jnp.float32)), h0)
 
 
 def _seg_rev_cumsum(dcum, r, masked):
-    """dla_t = sum_{i>=t, same segment} dC_i: the plain reverse cumsum minus
-    its value at the NEXT segment's start (gathered via the global segment
-    index) — exactly bounded, no sentinel arithmetic."""
-    rev = jnp.flip(jnp.cumsum(jnp.flip(dcum, axis=1), axis=1), axis=1)
+    """dla_t = sum_{i>=t, same segment} dC_i over the last axis of
+    ``dcum`` [B, H, S]: the plain reverse cumsum minus its value at the
+    NEXT segment's start (gathered via the global segment index) — exactly
+    bounded, no sentinel arithmetic."""
+    rev = jnp.flip(jnp.cumsum(jnp.flip(dcum, axis=2), axis=2), axis=2)
     if not masked:
         return rev
-    B, S, H = dcum.shape
-    seg = jnp.cumsum(r, axis=1)  # [B, S] global segment index
-    bidx = jnp.arange(B)[:, None]
+    B, H, S = dcum.shape
+    seg = jnp.cumsum(r, axis=1)[:, None, :]  # [B, 1, S] global segment index
+    bidx = jnp.arange(B)[:, None, None]
+    hidx = jnp.arange(H)[None, :, None]
     # rev at each segment's first (reset) position, scattered by segment id
-    starts = jnp.zeros((B, S + 2, H), dcum.dtype).at[
-        bidx, jnp.where(r > 0, seg, S + 1)
-    ].add(rev * (r > 0)[..., None].astype(dcum.dtype))
-    return rev - starts[bidx, jnp.minimum(seg + 1, S + 1)]
+    starts = jnp.zeros((B, H, S + 2), dcum.dtype).at[
+        bidx, hidx, jnp.where(r[:, None, :] > 0, seg, S + 1)
+    ].add(rev * (r[:, None, :] > 0).astype(dcum.dtype))
+    return rev - starts[bidx, hidx, jnp.minimum(seg + 1, S + 1)]
 
 
 def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
               masked):
-    B, S, H, dk = q.shape
+    B, H, S, dk = q.shape
     dv = v.shape[-1]
     Q = chunk
     n = S // Q
     grid = (B * H, n)
-    xmap, gmap, rmap, smap, cmap = _maps(H, n)
-
-    def rxmap(bh, j):  # chunks visited last-to-first
-        return (bh // H, n - 1 - j, bh % H, 0)
-
-    def rgmap(bh, j):
-        return (bh // H, n - 1 - j, bh % H)
-
-    def rrmap(bh, j):
-        return (bh // H, n - 1 - j)
-
-    def rcmap(bh, j):
-        return (bh, n - 1 - j, 0, 0)
+    xmap, rmap, smap, cmap = _maps(H, n)
+    rxmap, rrmap, _, rcmap = _maps(H, n, reverse=True)
+    la_c, li_c, r_c = _col(la), _col(li), _col(r.astype(jnp.float32))
 
     gexit, dh0 = pl.pallas_call(
         functools.partial(_bwd_state_kernel, n_chunks=n, chunk=Q,
                           masked=masked),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Q, 1, dk), rxmap),
-            pl.BlockSpec((1, Q, 1, dv), rxmap),
-            pl.BlockSpec((1, Q, 1), rgmap),
-            pl.BlockSpec((1, Q), rrmap),
+            pl.BlockSpec((1, 1, Q, dk), rxmap),
+            pl.BlockSpec((1, 1, Q, dv), rxmap),
+            pl.BlockSpec((1, 1, Q, 1), rxmap),
+            pl.BlockSpec((1, Q, 1), rrmap),
             pl.BlockSpec((1, 1, dk, dv), smap),
         ],
         out_specs=[
@@ -357,46 +379,47 @@ def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(q, dy, la, r, dhf)
+    )(q, dy, la_c, r_c, dhf)
 
     dq, dkk, dvv, dcum, dli = pl.pallas_call(
         functools.partial(_bwd_chunk_kernel, chunk=Q, masked=masked),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dv), xmap),
-            pl.BlockSpec((1, Q, 1), gmap),
-            pl.BlockSpec((1, Q, 1), gmap),
-            pl.BlockSpec((1, Q), rmap),
-            pl.BlockSpec((1, Q, 1, dv), xmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dv), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
+            pl.BlockSpec((1, Q, 1), rmap),
+            pl.BlockSpec((1, 1, Q, dv), xmap),
             pl.BlockSpec((1, 1, dk, dv), cmap),
             pl.BlockSpec((1, 1, dk, dv), cmap),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dk), xmap),
-            pl.BlockSpec((1, Q, 1, dv), xmap),
-            pl.BlockSpec((1, Q, 1), gmap),
-            pl.BlockSpec((1, Q, 1), gmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dk), xmap),
+            pl.BlockSpec((1, 1, Q, dv), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
+            pl.BlockSpec((1, 1, Q, 1), xmap),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct((B, S, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, S, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, la, li, r, dy, hin, gexit)
+    )(q, k, v, la_c, li_c, r_c, dy, hin, gexit)
 
     # dla_t = sum_{i>=t, same segment} (q_i.dq_i - k_i.dk_i); the final-state
     # term <dH_f, H_f> enters at the LAST position (so only the final
     # segment's positions see it) before the segment-bounded reverse cumsum.
-    dcum = dcum.at[:, -1, :].add(jnp.einsum("bhkv,bhkv->bh", dhf, hfin))
+    dcum = dcum[..., 0].at[:, :, -1].add(
+        jnp.einsum("bhkv,bhkv->bh", dhf, hfin))
     dla = _seg_rev_cumsum(dcum, r, masked)
     d_r = np.zeros(r.shape, jax.dtypes.float0)
-    return dq, dkk, dvv, dla, dli, d_r, dh0
+    return dq, dkk, dvv, dla, dli[..., 0], d_r, dh0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
@@ -448,7 +471,11 @@ def mamba_scan_pallas(
         # cumsum; this where also zeroes its log_decay gradient
         la = jnp.where(reset[:, :, None] > 0, 0.0, la)
         r = (reset > 0).astype(jnp.int32)
-    return _mamba_scan(
-        q, k, v, la, log_input.astype(jnp.float32), r, h0.astype(jnp.float32),
-        Q, interpret, reset is not None,
+    # head-major: each chunk is a (Q, d) tile of one head
+    y, h = _mamba_scan(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), la.transpose(0, 2, 1),
+        log_input.astype(jnp.float32).transpose(0, 2, 1), r,
+        h0.astype(jnp.float32), Q, interpret, reset is not None,
     )
+    return y.transpose(0, 2, 1, 3), h

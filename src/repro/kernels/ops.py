@@ -4,8 +4,8 @@ Each op has three execution paths:
   * ``xla``     — pure-jnp formulation (gather-einsum / flash-scan) that XLA
                   compiles well and GSPMD shards; default on CPU and in the
                   512-device dry-run.
-  * ``pallas``  — the TPU-target ``pl.pallas_call`` kernel (BlockSpec VMEM
-                  tiling); selected via ``set_impl("pallas")`` on TPU.
+  * ``pallas``  — the compiled ``pl.pallas_call`` kernel (BlockSpec VMEM
+                  tiling); the default whenever the backend is a TPU.
   * ``pallas_interpret`` — the same kernel body executed in interpret mode;
                   used by the CPU test suite to validate the kernel against
                   ``ref.py``.
@@ -72,18 +72,30 @@ from repro.kernels import ref as _ref
 
 class _Impl(threading.local):
     def __init__(self) -> None:
-        self.name = "xla"
+        self.name: Optional[str] = None  # resolved on first use, not import
 
 
 _IMPL = _Impl()
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def set_impl(name: str) -> None:
     assert name in ("xla", "pallas", "pallas_interpret"), name
+    if name == "pallas_interpret" and _on_tpu():
+        raise ValueError(
+            "pallas_interpret is the CPU test tier; a TPU runs the compiled "
+            "'pallas' tier")
     _IMPL.name = name
 
 
 def get_impl() -> str:
+    """The active tier: whatever ``set_impl`` chose on this thread, else
+    ``"pallas"`` on a TPU backend and ``"xla"`` everywhere else."""
+    if _IMPL.name is None:
+        _IMPL.name = "pallas" if _on_tpu() else "xla"
     return _IMPL.name
 
 
@@ -101,7 +113,7 @@ def grouped_lora(
     *,
     block_m: int = 128,
 ) -> jax.Array:
-    impl = _IMPL.name
+    impl = get_impl()
     B, S, d_in = x.shape
     if impl == "xla":
         # Batch-row gather: adapters indexed per row (B small), never per
@@ -113,16 +125,15 @@ def grouped_lora(
         h = jnp.einsum("bsd,bdr->bsr", x, a_r, preferred_element_type=jnp.float32)
         y = jnp.einsum("bsr,bro->bso", h, b_r.astype(jnp.float32))
         return (y * gate[:, None, None]).astype(x.dtype)
-    import math
-
     from repro.kernels.grouped_lora import grouped_lora_pallas
+    from repro.kernels.tiling import SUBLANE, fit_block
 
     xf = x.reshape(B * S, d_in)
     rows = jnp.repeat(row_task, S)
     # Tasks own whole batch rows, so any block_m dividing S keeps row_task
     # block-constant (the kernel's contract) — never straddle batch rows.
     out = grouped_lora_pallas(
-        xf, a, b, rows, scale, block_m=math.gcd(block_m, S),
+        xf, a, b, rows, scale, block_m=fit_block(S, block_m, SUBLANE),
         interpret=(impl == "pallas_interpret"),
     )
     return out.reshape(B, S, -1)
@@ -153,7 +164,7 @@ def packed_attention(
     XLA tier the prefix folds into the online-softmax carry init; on the
     Pallas tiers it enters the kernel as extra leading k/v segment rows with
     wildcard segment ids."""
-    impl = _IMPL.name
+    impl = get_impl()
     if impl == "xla":
         from repro.models.attention import flash_attention_pairs
 
@@ -183,13 +194,12 @@ def packed_attention(
     P = pk.shape[1]
     keep = prefix_keep if prefix_keep is not None else jnp.ones(
         (B, P), jnp.float32)
-    # Pad the prefix rows up to a tile-friendly count: block_k must divide
-    # S + P, and an unpadded P (e.g. 8 on S=512) would collapse the k-tile
-    # to gcd(S + P, block_k) and multiply kernel grid steps.  Pad rows are
-    # gated off (kseg = -2 matches no query), so they are pure masked work.
-    unit = math.gcd(math.gcd(S, block_k), 64)
-    if math.gcd(S + P, block_k) < min(unit, 32):
-        pad = (-P) % unit
+    # Pad the prefix rows so S + P keeps S's k-tile: an unpadded P (e.g. 16
+    # on S=2048) leaves S + P no lane-aligned divisor and collapses the
+    # k-tile to the whole sequence.  Pad rows are gated off (kseg = -2
+    # matches no query), so they are pure masked work.
+    pad = (-P) % math.gcd(S, block_k)
+    if pad:
         pk = jnp.pad(pk, ((0, 0), (0, pad), (0, 0), (0, 0)))
         pv = jnp.pad(pv, ((0, 0), (0, pad), (0, 0), (0, 0)))
         keep = jnp.pad(keep, ((0, 0), (0, pad)))
@@ -236,7 +246,7 @@ def decode_attention(
     the same window mask covers both.  Empty windows yield zeros (the
     denominator is clamped, never divided through).  The Pallas tiers read
     each KV element once via the split-KV kernel."""
-    impl = _IMPL.name
+    impl = get_impl()
     if impl == "xla":
         return _ref.decode_attention_ref(q, k_cache, v_cache, cache_len, cache_start)
     from repro.kernels.decode_attention import decode_attention_pallas
@@ -265,7 +275,7 @@ def quant_matmul(
     which is what lets the Pallas tiers flatten to one 2D
     ``y = (x @ q) * scale`` problem.  Gradients flow through ``x`` only.
     """
-    impl = _IMPL.name
+    impl = get_impl()
     if impl == "xla":
         # dequantize-then-einsum: the IDENTICAL graph to the dense BaseOp on
         # an explicitly dequantized weight (exact adapter-grad parity)
@@ -322,7 +332,7 @@ def mamba_scan(
     log-decay sentinel, which the f32 cumsum would absorb — so values match
     the segment-sliced oracle and gradients cannot leak across boundaries
     under autodiff of either path."""
-    impl = _IMPL.name
+    impl = get_impl()
     if impl == "xla":
         from repro.models.ssm import chunked_gla
 

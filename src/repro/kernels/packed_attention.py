@@ -12,9 +12,18 @@ Grid: (batch*heads, n_q, n_k), n_k innermost so the online-softmax scratch
 block still iterates but skips the MXU work, which is the grid-pruning
 analogue of flash attention's triangular traversal.
 
+Layout: the kernels see head-major ``[B, H, S, dh]`` operands (the wrapper
+transposes the model's ``[B, S, H, dh]``), so every block's last two dims
+are ``(block, dh)`` with ``dh`` the whole array dim — the TPU's (8, 128)
+tiling rule.  Per-row vectors follow the same rule: the query side is a
+column ``[B, S, 1]`` (block ``(1, block_q, 1)``), the key side a row
+``[B, 1, Sk]`` (block ``(1, 1, block_k)``), and the saved logsumexp a
+column ``[B, H, S, 1]``, so masks and softmax statistics are 2D broadcasts
+of ``(block_q, 1)`` against ``(1, block_k)``.
+
 Differentiable via ``jax.custom_vjp`` (flash-attention backward).  The
 forward under autodiff additionally emits the per-row logsumexp
-L = m + log(l) ([B, H, S] f32), so the backward never materializes the
+L = m + log(l) ([B, H, S, 1] f32), so the backward never materializes the
 [S, S] probability matrix: each tile recomputes p = exp(q k^T / sqrt(d) - L)
 from the saved L.  Two backward kernels mirror the forward traversal:
 
@@ -33,7 +42,6 @@ L = +1e30 so their p underflows to exactly zero in the backward.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -42,31 +50,53 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import LANE, SUBLANE, fit_block
+
 NEG_INF = -1e30
 LSE_MASKED = 1e30  # logsumexp sentinel for fully-masked rows
 
 
-def _tile_mask(qpos, kpos, qseg, kseg, causal, block_q, block_k):
-    mask = jnp.ones((block_q, block_k), bool)
-    if causal:
-        mask &= qpos[:, None] >= kpos[None, :]
+def _tile_mask(qpos, kpos, qseg, kseg, causal):
+    """(block_q, block_k) visibility from q-side columns and k-side rows."""
     # wildcard k rows: kseg == -1 matches EVERY query segment (learned
     # prefix-tuning k/v rows, gated per batch row); any other negative kseg
     # matches none (prefix rows of tasks the row does not belong to)
-    mask &= (qseg[:, None] == kseg[None, :]) | (kseg[None, :] == -1)
+    mask = (qseg == kseg) | (kseg == -1)
+    if causal:
+        mask &= qpos >= kpos
     return mask
 
 
+def _rows(qpos_ref, kpos_ref, qseg_ref, kseg_ref):
+    # q side: (block_q, 1) columns; k side: (1, block_k) rows
+    return qpos_ref[0], kpos_ref[0], qseg_ref[0], kseg_ref[0]
+
+
+def _scores(q_ref, k_ref, scale):
+    return jax.lax.dot_general(
+        q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [block_q, block_k]
+
+
+def _frontier(i, j, causal, block_q, block_k, k_offset):
+    """False for tiles strictly above the causal diagonal band
+    (``k_offset`` = leading always-visible k rows, e.g. learned prefixes)."""
+    if not causal:
+        return jnp.asarray(True)
+    return j * block_k <= (i + 1) * block_q - 1 + k_offset
+
+
 def _fwd_kernel(
-    q_ref,    # [1, block_q, 1, dh]
-    k_ref,    # [1, block_k, 1, dh]
-    v_ref,    # [1, block_k, 1, dh]
-    qpos_ref,  # [1, block_q]
-    kpos_ref,  # [1, block_k]
-    qseg_ref,  # [1, block_q]
-    kseg_ref,  # [1, block_k]
-    o_ref,    # [1, block_q, 1, dh]
-    *rest,    # (lse_ref? [1, 1, block_q], m_ref, l_ref, acc_ref)
+    q_ref,    # [1, 1, block_q, dh]
+    k_ref,    # [1, 1, block_k, dh]
+    v_ref,    # [1, 1, block_k, dh]
+    qpos_ref,  # [1, block_q, 1]
+    kpos_ref,  # [1, 1, block_k]
+    qseg_ref,  # [1, block_q, 1]
+    kseg_ref,  # [1, 1, block_k]
+    o_ref,    # [1, 1, block_q, dh]
+    *rest,    # (lse_ref? [1, 1, block_q, 1], m_ref, l_ref, acc_ref)
     n_k: int,
     causal: bool,
     scale: float,
@@ -85,54 +115,48 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal frontier: skip tiles strictly above the diagonal band
-    # (k_offset = leading always-visible k rows, e.g. learned prefixes)
-    run = (not causal) or (j * block_k <= (i + 1) * block_q - 1 + k_offset)
-    should_run = jnp.asarray(True) if run is True else jnp.asarray(run)
-
-    @pl.when(should_run)
+    @pl.when(_frontier(i, j, causal, block_q, block_k, k_offset))
     def _tile():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k]
-        mask = _tile_mask(qpos_ref[0], kpos_ref[0], qseg_ref[0], kseg_ref[0],
-                          causal, block_q, block_k)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        s = _scores(q_ref, k_ref, scale)
+        s = jnp.where(_tile_mask(*_rows(qpos_ref, kpos_ref, qseg_ref,
+                                        kseg_ref), causal), s, NEG_INF)
+        m_prev = m_ref[...]                                   # [block_q, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(j == n_k - 1)
     def _emit():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         if save_lse:
             m = m_ref[...]
-            rest[0][0, 0, :] = jnp.where(
+            rest[0][0, 0] = jnp.where(
                 m > NEG_INF * 0.5, m + jnp.log(jnp.maximum(l_ref[...], 1e-30)),
                 LSE_MASKED,
             )
 
 
+def _probs(q_ref, k_ref, rows, lse_ref, causal, scale):
+    s = _scores(q_ref, k_ref, scale)
+    return jnp.where(_tile_mask(*rows, causal), jnp.exp(s - lse_ref[0, 0]), 0.0)
+
+
 def _dq_kernel(
     q_ref, k_ref, v_ref,
     qpos_ref, kpos_ref, qseg_ref, kseg_ref,
-    do_ref,   # [1, block_q, 1, dh]
-    o_ref,    # [1, block_q, 1, dh]
-    lse_ref,  # [1, 1, block_q]
-    dq_ref,   # [1, block_q, 1, dh]
-    d_ref,    # [block_q] f32 scratch (D = rowsum(do * o))
+    do_ref,   # [1, 1, block_q, dh]
+    o_ref,    # [1, 1, block_q, dh]
+    lse_ref,  # [1, 1, block_q, 1]
+    dq_ref,   # [1, 1, block_q, dh]
+    d_ref,    # [block_q, 1] f32 scratch (D = rowsum(do * o))
     dq_acc,   # [block_q, dh] f32 scratch
     *,
     n_k: int,
@@ -147,47 +171,36 @@ def _dq_kernel(
 
     @pl.when(j == 0)
     def _init():
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        o = o_ref[0, :, 0, :].astype(jnp.float32)
-        d_ref[...] = (do * o).sum(axis=-1)
+        do = do_ref[0, 0].astype(jnp.float32)
+        o = o_ref[0, 0].astype(jnp.float32)
+        d_ref[...] = (do * o).sum(axis=-1, keepdims=True)
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    run = (not causal) or (j * block_k <= (i + 1) * block_q - 1 + k_offset)
-    should_run = jnp.asarray(True) if run is True else jnp.asarray(run)
-
-    @pl.when(should_run)
+    @pl.when(_frontier(i, j, causal, block_q, block_k, k_offset))
     def _tile():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        mask = _tile_mask(qpos_ref[0], kpos_ref[0], qseg_ref[0], kseg_ref[0],
-                          causal, block_q, block_k)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0, :][:, None]), 0.0)
+        p = _probs(q_ref, k_ref, _rows(qpos_ref, kpos_ref, qseg_ref, kseg_ref),
+                   lse_ref, causal, scale)
         dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            do_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         )  # [block_q, block_k]
-        ds = p * (dp - d_ref[...][:, None]) * scale
+        ds = p * (dp - d_ref[...]) * scale
         dq_acc[...] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds, k_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(j == n_k - 1)
     def _emit():
-        dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref,
     qpos_ref, kpos_ref, qseg_ref, kseg_ref,
     do_ref, o_ref, lse_ref,
-    dk_ref,   # [1, block_k, 1, dh] (per query head; group-summed outside)
-    dv_ref,   # [1, block_k, 1, dh]
+    dk_ref,   # [1, 1, block_k, dh] (per query head; group-summed outside)
+    dv_ref,   # [1, 1, block_k, dh]
     dk_acc,   # [block_k, dh] f32 scratch
     dv_acc,   # [block_k, dh] f32 scratch
     *,
@@ -206,40 +219,29 @@ def _dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (not causal) or ((i + 1) * block_q - 1 + k_offset >= j * block_k)
-    should_run = jnp.asarray(True) if run is True else jnp.asarray(run)
-
-    @pl.when(should_run)
+    @pl.when(_frontier(i, j, causal, block_q, block_k, k_offset))
     def _tile():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        o = o_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        mask = _tile_mask(qpos_ref[0], kpos_ref[0], qseg_ref[0], kseg_ref[0],
-                          causal, block_q, block_k)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0, :][:, None]), 0.0)
+        do = do_ref[0, 0].astype(jnp.float32)
+        p = _probs(q_ref, k_ref, _rows(qpos_ref, kpos_ref, qseg_ref, kseg_ref),
+                   lse_ref, causal, scale)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        d = (do * o).sum(axis=-1)  # [block_q]
+        d = (do * o_ref[0, 0].astype(jnp.float32)).sum(axis=-1, keepdims=True)
         dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            do, v_ref[0, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - d[:, None]) * scale
+        ds = p * (dp - d) * scale
         dk_acc[...] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            ds, q_ref[0, 0].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(i == n_q - 1)
     def _emit():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _specs(H, G, block_q, block_k, dh, *, kv_major):
@@ -250,34 +252,36 @@ def _specs(H, G, block_q, block_k, dh, *, kv_major):
         return (b, a) if kv_major else (a, b)
 
     def qi(bh, a, b):
-        return (bh // H, ij(a, b)[0], bh % H, 0)
+        return (bh // H, bh % H, ij(a, b)[0], 0)
 
     def kj(bh, a, b):
-        return (bh // H, ij(a, b)[1], (bh % H) // G, 0)
+        return (bh // H, (bh % H) // G, ij(a, b)[1], 0)
 
     def rq(bh, a, b):
-        return (bh // H, ij(a, b)[0])
+        return (bh // H, ij(a, b)[0], 0)
 
     def rk(bh, a, b):
-        return (bh // H, ij(a, b)[1])
-
-    def lse(bh, a, b):
-        return (bh // H, bh % H, ij(a, b)[0])
+        return (bh // H, 0, ij(a, b)[1])
 
     return {
-        "q": pl.BlockSpec((1, block_q, 1, dh), qi),
-        "k": pl.BlockSpec((1, block_k, 1, dh), kj),
-        "rowq": pl.BlockSpec((1, block_q), rq),
-        "rowk": pl.BlockSpec((1, block_k), rk),
-        "lse": pl.BlockSpec((1, 1, block_q), lse),
-        "qi": qi, "kj": kj,
+        "q": pl.BlockSpec((1, 1, block_q, dh), qi),
+        "k": pl.BlockSpec((1, 1, block_k, dh), kj),
+        "rowq": pl.BlockSpec((1, block_q, 1), rq),
+        "rowk": pl.BlockSpec((1, 1, block_k), rk),
+        "lse": pl.BlockSpec((1, 1, block_q, 1), qi),
     }
+
+
+def _row_operands(positions, segment_ids, k_positions, k_segment_ids):
+    """q-side ids as [B, S, 1] columns, k-side ids as [B, 1, Sk] rows."""
+    return (positions[:, :, None], k_positions[:, None, :],
+            segment_ids[:, :, None], k_segment_ids[:, None, :])
 
 
 def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
               causal, block_q, block_k, interpret, save_lse):
-    B, S, H, dh = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
+    B, H, S, dh = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
     G = H // Hkv
     n_q, n_k = S // block_q, Sk // block_k
     sp = _specs(H, G, block_q, block_k, dh, kv_major=False)
@@ -285,7 +289,7 @@ def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     out_specs = [sp["q"]]
     if save_lse:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, S), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32))
         out_specs.append(sp["lse"])
 
     fn = pl.pallas_call(
@@ -300,24 +304,26 @@ def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
     )
-    out = fn(q, k, v, positions, k_positions, segment_ids, k_segment_ids)
+    out = fn(q, k, v, *_row_operands(positions, segment_ids, k_positions,
+                                     k_segment_ids))
     return out if save_lse else out[0]
 
 
 def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
               o, lse, do, causal, block_q, block_k, interpret):
-    B, S, H, dh = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
+    B, H, S, dh = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
     G = H // Hkv
     n_q, n_k = S // block_q, Sk // block_k
     scale = 1.0 / np.sqrt(dh)
     k_offset = Sk - S
+    rows = _row_operands(positions, segment_ids, k_positions, k_segment_ids)
 
     sp = _specs(H, G, block_q, block_k, dh, kv_major=False)
     dq = pl.pallas_call(
@@ -332,17 +338,17 @@ def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
         out_specs=sp["q"],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, positions, k_positions, segment_ids, k_segment_ids, do, o, lse)
+    )(q, k, v, *rows, do, o, lse)
 
     sp = _specs(H, G, block_q, block_k, dh, kv_major=True)
     # dk/dv are accumulated per QUERY head (block written once per (bh, j))
     # and group-summed to the Hkv axis outside the kernel.
     dkq_spec = pl.BlockSpec(
-        (1, block_k, 1, dh), lambda bh, j, i: (bh // H, j, bh % H, 0)
+        (1, 1, block_k, dh), lambda bh, j, i: (bh // H, bh % H, j, 0)
     )
     dkq, dvq = pl.pallas_call(
         functools.partial(
@@ -355,18 +361,18 @@ def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
                   sp["q"], sp["q"], sp["lse"]],
         out_specs=[dkq_spec, dkq_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, H, dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, dh), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dh), jnp.float32),
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, positions, k_positions, segment_ids, k_segment_ids, do, o, lse)
+    )(q, k, v, *rows, do, o, lse)
 
-    dk = dkq.reshape(B, Sk, Hkv, G, dh).sum(axis=3).astype(k.dtype)
-    dv = dvq.reshape(B, Sk, Hkv, G, dh).sum(axis=3).astype(v.dtype)
+    dk = dkq.reshape(B, Hkv, G, Sk, dh).sum(axis=2).astype(k.dtype)
+    dv = dvq.reshape(B, Hkv, G, Sk, dh).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -422,8 +428,10 @@ def packed_attention_pallas(
     row, any other negative value a row visible to none."""
     B, S, H, dh = q.shape
     Sk = k.shape[1]
-    block_q = math.gcd(S, min(block_q, S))
-    block_k = math.gcd(Sk, min(block_k, Sk))
+    # q tiles are sublane blocks of the [.., S, dh] operands and columns of
+    # the q-side ids; k tiles are also lane blocks of the k-side id rows
+    block_q = fit_block(S, block_q, SUBLANE)
+    block_k = fit_block(Sk, block_k, LANE)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     if segment_ids is None:
@@ -434,8 +442,11 @@ def packed_attention_pallas(
     if k_segment_ids is None:
         assert Sk == S, "k-side segment ids required when Sk != S"
         k_segment_ids = segment_ids
-    return _packed_attention(
-        q, k, v, positions.astype(jnp.int32), segment_ids.astype(jnp.int32),
-        k_positions.astype(jnp.int32), k_segment_ids.astype(jnp.int32),
-        causal, block_q, block_k, interpret,
+    head_major = (0, 2, 1, 3)
+    o = _packed_attention(
+        q.transpose(head_major), k.transpose(head_major),
+        v.transpose(head_major), positions.astype(jnp.int32),
+        segment_ids.astype(jnp.int32), k_positions.astype(jnp.int32),
+        k_segment_ids.astype(jnp.int32), causal, block_q, block_k, interpret,
     )
+    return o.transpose(head_major)

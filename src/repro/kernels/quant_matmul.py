@@ -6,7 +6,8 @@ per-output-channel scale.  The hot-path matmul must NOT materialize the
 dequantized weight in HBM — that would forfeit the 2x byte win that lets
 more tenants co-reside.  Instead this kernel streams int8 weight tiles into
 VMEM, casts to f32 *in register*, accumulates x @ q in an f32 VMEM scratch
-over k-tiles, and applies the per-column scale once at the final emit:
+over k-tiles of each (block_m, block_n) output tile, and applies the
+per-column scale once at the final emit:
 
     y[M, N] = (x[M, K] @ q[K, N].astype(f32)) * scale[N]
 
@@ -25,24 +26,25 @@ loop never differentiates).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import LANE, SUBLANE, fit_block
+
 
 def _qmm_kernel(
     x_ref,      # [block_m, block_k]
-    q_ref,      # [block_k, N] int8
-    s_ref,      # [1, N] f32
-    o_ref,      # [block_m, N]
-    acc_ref,    # [block_m, N] f32 scratch
+    q_ref,      # [block_k, block_n] int8
+    s_ref,      # [1, block_n] f32
+    o_ref,      # [block_m, block_n]
+    acc_ref,    # [block_m, block_n] f32 scratch
     *,
     n_k: int,
 ):
-    k = pl.program_id(1)
+    k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -60,21 +62,22 @@ def _qmm_kernel(
         o_ref[...] = (acc_ref[...] * s_ref[...]).astype(o_ref.dtype)
 
 
-def _qmm_call(x, q, scale, *, block_m: int, block_k: int, interpret: bool):
+def _qmm_call(x, q, scale, *, block_m: int, block_n: int, block_k: int,
+              interpret: bool):
     M, K = x.shape
     N = q.shape[1]
-    n_m, n_k = M // block_m, K // block_k
+    n_m, n_n, n_k = M // block_m, N // block_n, K // block_k
     return pl.pallas_call(
         functools.partial(_qmm_kernel, n_k=n_k),
-        grid=(n_m, n_k),
+        grid=(n_m, n_n, n_k),
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, k: (i, k)),
-            pl.BlockSpec((block_k, N), lambda i, k: (k, 0)),
-            pl.BlockSpec((1, N), lambda i, k: (0, 0)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, N), lambda i, k: (i, 0)),
+        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
     )(x, q, scale.reshape(1, N))
 
@@ -85,11 +88,14 @@ def quant_matmul_pallas(
     scale: jax.Array,  # [N] f32
     *,
     block_m: int = 128,
+    block_n: int = 1024,
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """y = (x @ dequant(q, scale)) with the dequant fused into the kernel.
 
+    Tiles are (block_m, block_k) x (block_k, block_n): N is tiled too, so the
+    weight tile and the f32 accumulator stay a few MiB at any d_ff.
     Differentiable w.r.t. ``x`` only (the backbone is frozen); the backward
     contracts the cotangent against the int8 blocks directly.
     """
@@ -97,13 +103,14 @@ def quant_matmul_pallas(
     K2, N = q.shape
     assert K == K2, (x.shape, q.shape)
     assert scale.shape == (N,), (scale.shape, N)
-    block_m = math.gcd(M, block_m)
-    block_k = math.gcd(K, block_k)
+    block_m = fit_block(M, block_m, SUBLANE)
+    block_n = fit_block(N, block_n, LANE)
+    block_k = fit_block(K, block_k, LANE)
 
     @jax.custom_vjp
     def qmm(x):
-        return _qmm_call(x, q, scale, block_m=block_m, block_k=block_k,
-                         interpret=interpret)
+        return _qmm_call(x, q, scale, block_m=block_m, block_n=block_n,
+                         block_k=block_k, interpret=interpret)
 
     def fwd(x):
         return qmm(x), None

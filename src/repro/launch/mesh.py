@@ -10,8 +10,6 @@ import numpy as np
 
 import jax
 
-from repro.compat import make_mesh as _compat_make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -23,10 +21,15 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}; have {len(devices)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512"
         )
-    return _compat_make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_mesh(shape, axes):
     """Generic helper for tests/benchmarks with small device counts."""
     n = int(np.prod(shape))
-    return _compat_make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
+
+
+def _auto_mesh(shape, axes, devices):
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -14,14 +14,16 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
+import tempfile
 
-import numpy as np
+import jax
 
-from repro.configs import get_config, smoke_config
+from repro.configs import get_config
 from repro.core import ExecutionPlanner, ModelGenerator, ParallelismSpec, PEFTEngine
 from repro.data import HTaskLoader, make_task
 from repro.distributed.fault_tolerance import SupervisorConfig, TrainSupervisor
+from repro.kernels import ops as kops
+from repro.launch.compile_cache import configure_compile_cache
 from repro.peft.adapters import LORA
 from repro.peft.methods import AdapterConfig
 from repro.peft.methods import resolve_kind
@@ -73,11 +75,16 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--tasks", default="sst2:lora:8,qa:lora:8,rte:adapter:4,sst2:ia3")
     ap.add_argument("--stages", type=int, default=4)
-    ap.add_argument("--ckpt-dir", default="/tmp/muxtune_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "muxtune_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--alignment", default="chunked", choices=["chunked", "zero_pad", "pack_only"])
     args = ap.parse_args()
 
+    cache_dir = configure_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device={dev.platform}:{dev.device_kind} x{len(jax.devices())} "
+          f"kernels={kops.get_impl()} compile_cache={cache_dir}")
     cfg = scaled_config(args.arch, args.scale)
     tasks = parse_tasks(args.tasks, args.micro_batch)
     print(f"arch={cfg.name} d={cfg.d_model} L={cfg.num_layers} "

@@ -199,8 +199,6 @@ def striped_cp_attention(
 
     bspec = P(dp_axes if dp_axes else None, axis, None, None)
     pspec = P(dp_axes if dp_axes else None, axis)
-    from repro.compat import shard_map
-
     in_specs = [bspec, bspec, bspec, pspec, pspec]
     args = [q, k, v, positions, seg]
     if kv_prefix is not None:
@@ -209,7 +207,7 @@ def striped_cp_attention(
         pkeep = P(dp_axes if dp_axes else None, None)
         in_specs += [prow, prow, pkeep]
         args += list(kv_prefix)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=bspec,

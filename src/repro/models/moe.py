@@ -19,7 +19,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -156,7 +155,7 @@ def moe_apply(
 
     bspec = P(dp_axes if dp_axes else None, None, None)
     espec = P(ep_axis, None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None), espec, espec, espec),
@@ -305,7 +304,7 @@ def moe_apply_a2a(
         espec_out = P(ep_axis, None, fsdp_axis)
     else:
         espec_in = espec_out = P(ep_axis, None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None), espec_in, espec_in, espec_out),

@@ -20,23 +20,29 @@ from __future__ import annotations
 
 import heapq
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import List, Optional, Sequence, Tuple
 
 from repro.configs import ArchConfig
-from repro.core.cost_model import CostModel, HardwareProfile, HBM_BYTES
+from repro.core.cost_model import CostModel, HardwareProfile
 from repro.core.fusion import build_htask
 from repro.core.task import ParallelismSpec, PEFTTask
 
 
 @dataclass(frozen=True)
 class AdmissionConfig:
-    memory_budget: float = HBM_BYTES
+    memory_budget: Optional[float] = None  # None: the device's HBM
     max_tenants: int = 8
     max_queue: int = 16
     # admit while fused-stage latency <= cap * slowest solo-tenant latency
     saturation_cap: float = 4.0
     alignment_mode: str = "chunked"
+
+    def for_profile(self, hw: HardwareProfile) -> "AdmissionConfig":
+        """This config with an unset memory budget resolved to ``hw``'s HBM."""
+        if self.memory_budget is not None:
+            return self
+        return replace(self, memory_budget=hw.hbm_bytes)
 
 
 @dataclass(frozen=True)
@@ -66,8 +72,8 @@ class AdmissionController:
         would let admission accept sets the planner then deems infeasible)."""
         self.cfg = cfg
         self.parallelism = parallelism
-        self.hw = hw or HardwareProfile()
-        self.config = config or AdmissionConfig()
+        self.hw = hw or HardwareProfile.for_device()
+        self.config = (config or AdmissionConfig()).for_profile(self.hw)
         self._cost_model_fn = cost_model_fn
 
     # ------------------------------------------------------------------

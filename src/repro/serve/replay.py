@@ -37,6 +37,7 @@ from repro.cluster.simulator import ClusterSim, TaskArrival, philly_style_trace
 from repro.configs import smoke_config
 from repro.core.task import ParallelismSpec, PEFTTask
 from repro.data.synthetic import make_task
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs.log import get_logger
 from repro.obs.tracing import SpanTracer, set_tracer
 from repro.peft.adapters import ADAPTER_TUNING, LORA
@@ -226,6 +227,9 @@ def replay_fleet(
     its tenants recover onto survivors from their latest committed
     checkpoints and their in-flight requests are re-created there.
 
+    Instance i computes on chip ``i % jax.device_count()``: one chip per
+    instance on a multi-chip host.
+
     Fusion stays off fleet-wide so a migrated tenant's data stream (and
     therefore its loss trajectory) is exactly its solo trajectory."""
     from repro.fleet import Autoscaler, FleetRouter
@@ -402,6 +406,7 @@ def main() -> None:
                          "trained steps (0 disables; enables the warm "
                          "recovery path under --kill-instance)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.philly:
         trace = philly_style_trace(horizon_min=args.tenants * 2.0,
                                    rate_per_min=0.5, mean_dur_min=5.0)

@@ -28,10 +28,12 @@ simulator's abstract predictions can be validated against real execution
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.configs import ArchConfig
@@ -191,6 +193,19 @@ class TenantRecord:
         }
 
 
+def _on_device(method):
+    """Run a service entry point with the service's device as JAX's default
+    device, so every array it allocates and every step it compiles and runs
+    stays on that chip (``device=None``: JAX's own default)."""
+
+    @functools.wraps(method)
+    def pinned(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+
+    return pinned
+
+
 class MuxTuneService:
     def __init__(
         self,
@@ -213,12 +228,17 @@ class MuxTuneService:
         fault_dir: Optional[str] = None,
         ckpt_cadence: int = 0,
     ):
+        # the chip this instance computes on (None: JAX's default); the fleet
+        # router pins instance i to chip i.  Entry points run under it via
+        # ``_on_device``.
+        self.device: Optional[jax.Device] = None
         self.cfg = cfg
         self.parallelism = parallelism or ParallelismSpec()
         self.lr = lr
         self.n_micro = n_micro
         self.enable_fusion = enable_fusion
-        self.admission_config = admission or AdmissionConfig()
+        hw = hw or HardwareProfile.for_device()
+        self.admission_config = (admission or AdmissionConfig()).for_profile(hw)
         self.planner = ExecutionPlanner(
             cfg, self.parallelism, hw,
             memory_budget=self.admission_config.memory_budget)
@@ -321,6 +341,7 @@ class MuxTuneService:
     # ------------------------------------------------------------------
     # tenant lifecycle
 
+    @_on_device
     def submit(self, spec, **legacy) -> TenantRecord:
         """Admit, queue or reject one tenant.  New API: ``submit(TenantSpec)``
         — the legacy ``submit(task, priority=..., target_steps=...,
@@ -354,6 +375,7 @@ class MuxTuneService:
                                reason=decision.reason).inc()
         return rec
 
+    @_on_device
     def submit_request(self, task_id: str, prompt, **legacy
                        ) -> InferenceRequest:
         """Submit an inference request against a tenant's adapter stack.
@@ -383,10 +405,12 @@ class MuxTuneService:
             return self.coserve.reject(req, "family_unsupported")
         return self.coserve.submit(req)
 
+    @_on_device
     def cancel_request(self, request_id: str) -> InferenceRequest:
         self.coserve.cancel(request_id, self.clock, reason="user_cancel")
         return self.coserve.requests[request_id]
 
+    @_on_device
     def cancel(self, task_id: str) -> TenantRecord:
         rec = self.tenants[task_id]
         if rec.state == QUEUED:
@@ -402,6 +426,7 @@ class MuxTuneService:
     # ------------------------------------------------------------------
     # live migration hooks (fleet tier: repro.fleet.migration drives these)
 
+    @_on_device
     def drain_tenant(self, task_id: str) -> List[InferenceRequest]:
         """Migration phase 1 (drain): pull the tenant's live decode requests
         out of the scheduler via the pool-generation recovery semantics —
@@ -445,6 +470,7 @@ class MuxTuneService:
                 np.asarray(self.engine._slot_steps[kind])[slot])
         return sub, extra
 
+    @_on_device
     def checkpoint_out_tenant(self, task_id: str, ckpt_dir: str,
                               include_optimizer: bool = True) -> str:
         """Migration phase 2 (checkpoint out): atomically checkpoint one
@@ -491,6 +517,7 @@ class MuxTuneService:
         self.telemetry.counter("service.checkpoint",
                                direction="cadence").inc()
 
+    @_on_device
     def release_tenant(self, task_id: str, ckpt_dir: str,
                        requests: Optional[List[InferenceRequest]] = None,
                        ) -> MigrationTicket:
@@ -519,6 +546,7 @@ class MuxTuneService:
         self.telemetry.counter("service.migrations", direction="out").inc()
         return ticket
 
+    @_on_device
     def migrate_in(self, ticket: MigrationTicket) -> TenantRecord:
         """Migration phase 4 (warm start): admit a migrated tenant with its
         full optimizer state.  Re-binding the drained inference requests is
@@ -563,6 +591,7 @@ class MuxTuneService:
                                reason=decision.reason).inc()
         return rec
 
+    @_on_device
     def adopt_requests(self, requests: List[InferenceRequest]) -> None:
         """Migration phase 5 (re-bind): adopt drained requests from a source
         instance.  They queue for pool rows like fresh submissions — the
@@ -773,6 +802,7 @@ class MuxTuneService:
     # ------------------------------------------------------------------
     # data plane
 
+    @_on_device
     def step(self) -> Optional[StepMetrics]:
         """One engine iteration for the current resident set, with any
         waiting inference traffic token-level interleaved under the SLO;

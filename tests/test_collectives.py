@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.distributed.collectives import (
     compression_error,
     dequantize_int8,
@@ -34,10 +34,10 @@ def test_quantize_shapes(key):
 
 def test_int8_psum_single_device(key):
     """With axis size 1, the quantized psum == local dequantized value."""
-    mesh = compat.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     x = jax.random.normal(key, (512,))
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         lambda v: int8_psum(v, "d"), mesh=mesh,
         in_specs=P(), out_specs=P(), check_vma=False,
     )(x)
@@ -45,9 +45,9 @@ def test_int8_psum_single_device(key):
 
 
 def test_psum_tree_compressed(key):
-    mesh = compat.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     tree = {"a": jax.random.normal(key, (64, 8)), "b": jax.random.normal(key, (17,))}
-    out = compat.shard_map(
+    out = jax.shard_map(
         lambda t: psum_tree(t, "d", compress=True), mesh=mesh,
         in_specs=(P(),), out_specs=P(), check_vma=False,
     )(tree)
@@ -70,7 +70,7 @@ def test_logical_to_spec_dedup():
 def test_rules_mesh_axes_filter():
     import jax
 
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r = ShardingRules().mesh_axes(mesh)
     assert r.lookup("batch") == ("data",)
     assert r.lookup("ff") is None  # "model" absent from this mesh
@@ -81,7 +81,7 @@ def test_rules_for_decode_cache_layout():
     from repro.launch.rules import rules_for
     import jax
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("yi-34b")
     r = rules_for(cfg, SHAPES["decode_32k"], mesh)
     assert r.lookup("seq") is None  # decode: no seq sharding of 1-token input
@@ -101,7 +101,7 @@ def test_opt_shardings_task_axis():
     mta = MultiTaskAdapters(cfg, [AdapterConfig(LORA, rank=4)] * 2)
     opt_specs = jax.eval_shape(adamw_init, mta.abstract())
     # abstract mesh: spec construction needs no physical 2-device host
-    mesh = compat.make_abstract_mesh((2, 1), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
 
     shard = opt_shardings(opt_specs, mesh, mta=mta, cfg=cfg)
     # dense family: adapter leaves are [layers, tasks, ...] -> task axis 1

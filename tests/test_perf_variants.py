@@ -109,8 +109,8 @@ _SUBPROC = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_multi_device_variants_subprocess():
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("JAX_PLATFORMS", None)
+    # the child forces 8 host devices; it must never reach for a chip
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _SUBPROC], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SUBPROC_OK" in out.stdout, out.stderr[-2000:]

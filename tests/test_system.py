@@ -148,3 +148,24 @@ def test_nan_guard_isolates_diverging_task():
     # adapters themselves must not have been moved by a NaN update
     ad = eng.reg.adapter_params["lora"]["attn_q"]["b"]
     assert np.isfinite(np.asarray(ad, np.float32)).all()
+
+
+def test_token_stream_same_in_every_process():
+    """The same command trains on the same tokens in every interpreter,
+    whatever its string-hash salt."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import itertools; from repro.data.synthetic import token_stream; "
+            "print(list(itertools.islice(token_stream('sst2-t0', 49152, 3), 32)))")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    outs = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONPATH=src, PYTHONHASHSEED=salt,
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        outs.append(out.stdout)
+    assert outs[0] == outs[1]
