@@ -1,0 +1,7 @@
+"""Device time of the ``packed_attention_dkv`` Pallas kernel inside the
+training steps, per traced step (ms)."""
+from devtime import kernel_ms_per_step
+
+
+def read(ctx):
+    return kernel_ms_per_step(ctx["trace"], "packed_attention_dkv")

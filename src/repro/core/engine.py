@@ -56,8 +56,6 @@ class StepMetrics:
     # traffic rode along with this training iteration
     decode_tokens: int = 0
     decode_seconds: float = 0.0
-    decode_p50_s: float = 0.0   # windowed per-token latency percentiles
-    decode_p99_s: float = 0.0
     decode_micro_steps: int = 0  # fused micro-steps in the timed segment
 
     @property
@@ -275,13 +273,20 @@ class PEFTEngine:
         }
         masks = self._broadcast_slots(mask_vecs)
 
+        # Named scopes label the step's phases in the device trace (HLO
+        # op_name metadata only: the compiled program is otherwise the
+        # same).  Backward ops carry ``transpose(`` in their op_name, the
+        # forward recomputed inside the backward included.
         def loss_fn(adapters, backbone, batch):
-            out = model.forward(backbone, batch, adapters=adapters, ctx_factory=ctxf)
-            pt = local_seg.per_task_loss(out["per_token_loss"], batch["loss_mask"])
-            loss = pt.sum()
-            for k, v in out["aux"].items():
-                if k == "moe_load_balance":
-                    loss = loss + aux_coef * v
+            with jax.named_scope("train.forward"):
+                out = model.forward(backbone, batch, adapters=adapters,
+                                    ctx_factory=ctxf)
+                pt = local_seg.per_task_loss(out["per_token_loss"],
+                                             batch["loss_mask"])
+                loss = pt.sum()
+                for k, v in out["aux"].items():
+                    if k == "moe_load_balance":
+                        loss = loss + aux_coef * v
             return loss, pt
 
         def step(backbone, adapters, opt_state, slot_steps, batch, member_ids, acc):
@@ -293,38 +298,40 @@ class PEFTEngine:
                 loss_fn, has_aux=True, allow_int=True
             )(adapters, backbone, batch)
             prev_opt = opt_state
-            finite = jnp.isfinite(loss)
-            # NaN guard composes with member masking: a diverging step keeps
-            # non-members untouched by construction and reverts members.
-            counts = {k: jnp.where(finite, v + mask_vecs[k], v)
-                      for k, v in slot_steps.items()}
-            step_counts = self._broadcast_slots(counts)
-            updates, opt_state = adamw_update(
-                grads, opt_state, adapters, lr=lr, lr_scales=lr_scales,
-                step_counts=step_counts,
-            )
+            with jax.named_scope("train.optimizer"):
+                finite = jnp.isfinite(loss)
+                # NaN guard composes with member masking: a diverging step
+                # keeps non-members untouched by construction and reverts
+                # members.
+                counts = {k: jnp.where(finite, v + mask_vecs[k], v)
+                          for k, v in slot_steps.items()}
+                step_counts = self._broadcast_slots(counts)
+                updates, opt_state = adamw_update(
+                    grads, opt_state, adapters, lr=lr, lr_scales=lr_scales,
+                    step_counts=step_counts,
+                )
 
-            def guard_update(u, mk):
-                if u is None:
-                    return None
-                m = 1.0 if mk is None else mk.astype(u.dtype)
-                return jnp.where(finite, u * m, jnp.zeros_like(u))
+                def guard_update(u, mk):
+                    if u is None:
+                        return None
+                    m = 1.0 if mk is None else mk.astype(u.dtype)
+                    return jnp.where(finite, u * m, jnp.zeros_like(u))
 
-            def guard_moment(new, old, mk):
-                if new is None:
-                    return None
-                keep = finite if mk is None else (finite & (mk > 0))
-                return jnp.where(keep, new, old)
+                def guard_moment(new, old, mk):
+                    if new is None:
+                        return None
+                    keep = finite if mk is None else (finite & (mk > 0))
+                    return jnp.where(keep, new, old)
 
-            updates = jax.tree.map(guard_update, updates, masks,
-                                   is_leaf=lambda x: x is None)
-            opt_state = opt_state._replace(
-                m=jax.tree.map(guard_moment, opt_state.m, prev_opt.m, masks,
-                               is_leaf=lambda x: x is None),
-                v=jax.tree.map(guard_moment, opt_state.v, prev_opt.v, masks,
-                               is_leaf=lambda x: x is None),
-            )
-            adapters = apply_updates(adapters, updates)
+                updates = jax.tree.map(guard_update, updates, masks,
+                                       is_leaf=lambda x: x is None)
+                opt_state = opt_state._replace(
+                    m=jax.tree.map(guard_moment, opt_state.m, prev_opt.m, masks,
+                                   is_leaf=lambda x: x is None),
+                    v=jax.tree.map(guard_moment, opt_state.v, prev_opt.v, masks,
+                                   is_leaf=lambda x: x is None),
+                )
+                adapters = apply_updates(adapters, updates)
             total, pt_acc = acc
             total = total + loss
             pt_acc = pt_acc.at[member_ids].add(pt)

@@ -135,6 +135,7 @@ def decode_attention_pallas(
             jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(len_b, start_b, q4, k4, v4)
 
     # stage 2: online-softmax combine across splits (tiny reduction)

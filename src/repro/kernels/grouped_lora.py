@@ -190,6 +190,7 @@ def _fwd_call(x, a, b, row_task, scale, block_m, block_k, interpret, save_h):
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="grouped_lora_fwd",
     )
     out = fn(block_task, scale.astype(jnp.float32),
              x.reshape(n_m, block_m, d_in), a, b)
@@ -234,6 +235,7 @@ def _bwd_call(x, a, b, row_task, scale, h, g, block_m, block_k, interpret):
             jax.ShapeDtypeStruct((n_m, r, d_out), jnp.float32),
         ],
         interpret=interpret,
+        name="grouped_lora_bwd",
     )
     dx, da_p, dm_p = fn(block_task, scale.astype(jnp.float32),
                         x.reshape(n_m, block_m, d_in),

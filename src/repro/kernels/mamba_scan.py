@@ -325,6 +325,7 @@ def _fwd_call(q, k, v, la, li, r, h0, chunk, interpret, masked, save_states):
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
+        name="mamba_scan_fwd",
     )(q, k, v, _col(la), _col(li), _col(r.astype(jnp.float32)), h0)
 
 
@@ -379,6 +380,7 @@ def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
+        name="mamba_scan_bwd_state",
     )(q, dy, la_c, r_c, dhf)
 
     dq, dkk, dvv, dcum, dli = pl.pallas_call(
@@ -410,6 +412,7 @@ def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mamba_scan_bwd_chunk",
     )(q, k, v, la_c, li_c, r_c, dy, hin, gexit)
 
     # dla_t = sum_{i>=t, same segment} (q_i.dq_i - k_i.dk_i); the final-state

@@ -309,6 +309,7 @@ def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="packed_attention_fwd",
     )
     out = fn(q, k, v, *_row_operands(positions, segment_ids, k_positions,
                                      k_segment_ids))
@@ -342,6 +343,7 @@ def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="packed_attention_dq",
     )(q, k, v, *rows, do, o, lse)
 
     sp = _specs(H, G, block_q, block_k, dh, kv_major=True)
@@ -369,6 +371,7 @@ def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="packed_attention_dkv",
     )(q, k, v, *rows, do, o, lse)
 
     dk = dkq.reshape(B, Hkv, G, Sk, dh).sum(axis=2).astype(k.dtype)

@@ -79,6 +79,7 @@ def _qmm_call(x, q, scale, *, block_m: int, block_n: int, block_k: int,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(x, q, scale.reshape(1, N))
 
 

@@ -21,6 +21,7 @@ from repro.configs import ArchConfig, ShapeSpec
 from repro.distributed.sharding import ShardingRules, activate_rules, logical_to_spec
 from repro.models.layers import abstract, is_spec_leaf, spec_logical_axes
 from repro.models.transformer import Model
+from repro.obs.tracing import span
 from repro.peft.adapters import LORA
 from repro.peft.methods import AdapterConfig
 from repro.peft.multitask import MultiTaskAdapters, TaskSegments
@@ -253,11 +254,16 @@ def prefetch_to_device(it, size: int = 2, shardings: Optional[Dict] = None):
     buf: deque = deque()
 
     def fill() -> None:
+        # the host fill and the copy are spans of their own, so a device
+        # idle gap goes to the one the host was in
         while len(buf) < size:
             try:
-                buf.append(device_put_batch(next(it), shardings))
+                with span("engine.prefetch.fill", track="engine"):
+                    host = next(it)
             except StopIteration:
                 return
+            with span("engine.prefetch.h2d", track="engine"):
+                buf.append(device_put_batch(host, shardings))
 
     fill()
     while buf:
@@ -276,18 +282,21 @@ def build_train_step(model: Model, mta: MultiTaskAdapters, segments: TaskSegment
     ctxf = mta.ctx_factory(segments)
 
     def train_step(backbone, adapters, opt_state, batch):
+        # phase scopes as in ``PEFTEngine._make_step``
         def loss_fn(ad):
-            out = model.forward(backbone, batch, adapters=ad, ctx_factory=ctxf)
-            pt = segments.per_task_loss(out["per_token_loss"], batch["loss_mask"])
-            loss = pt.sum()
-            for k, v in out["aux"].items():
-                if k == "moe_load_balance":
-                    loss = loss + aux_coef * v
+            with jax.named_scope("train.forward"):
+                out = model.forward(backbone, batch, adapters=ad, ctx_factory=ctxf)
+                pt = segments.per_task_loss(out["per_token_loss"], batch["loss_mask"])
+                loss = pt.sum()
+                for k, v in out["aux"].items():
+                    if k == "moe_load_balance":
+                        loss = loss + aux_coef * v
             return loss, pt
 
         (loss, pt), grads = jax.value_and_grad(loss_fn, has_aux=True, allow_int=True)(adapters)
-        updates, opt_state = adamw_update(grads, opt_state, adapters, lr=lr)
-        adapters = apply_updates(adapters, updates)
+        with jax.named_scope("train.optimizer"):
+            updates, opt_state = adamw_update(grads, opt_state, adapters, lr=lr)
+            adapters = apply_updates(adapters, updates)
         return adapters, opt_state, loss, pt
 
     return train_step

@@ -838,9 +838,6 @@ class MuxTuneService:
             metrics.decode_tokens = dtok
             metrics.decode_seconds = dwall
             metrics.decode_micro_steps = k
-            pct = self.coserve.latency_percentiles()
-            metrics.decode_p50_s = pct["decode_p50_s"]
-            metrics.decode_p99_s = pct["decode_p99_s"]
             for tid, n in per_task.items():
                 rec = self.tenants.get(tid)
                 if rec is not None:

@@ -9,6 +9,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and each test worker imports every
 test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -61,9 +63,14 @@ def _spec(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_for_chip(fn, *args):
+def _compile_for_chip(fn, *args, names=()):
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel"
+    # each kernel's name reaches its HLO op_name, in a transform's
+    # parentheses where the kernel is the outermost call under one
+    found = set(re.findall(r'([A-Za-z0-9_]+)\)*/pallas_call"', text))
+    assert set(names) <= found, (names, found)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -91,7 +98,8 @@ def test_grouped_lora_compiles(one_chip, d_in):
     _compile_for_chip(
         fwd_bwd, _spec(one_chip, (M, d_in)), _spec(one_chip, (T, d_in, r)),
         _spec(one_chip, (T, r, d_out)), _spec(one_chip, (M,), jnp.int32),
-        _spec(one_chip, (T,), jnp.float32))
+        _spec(one_chip, (T,), jnp.float32),
+        names=("grouped_lora_fwd", "grouped_lora_bwd"))
 
 
 def test_packed_attention_compiles(one_chip):
@@ -99,7 +107,9 @@ def test_packed_attention_compiles(one_chip):
     qkv = (_spec(one_chip, (B, S, H, dh)), _spec(one_chip, (B, S, Hkv, dh)),
            _spec(one_chip, (B, S, Hkv, dh)))
     _compile_for_chip(packed_attention_pallas, *qkv)
-    _compile_for_chip(_grads(packed_attention_pallas, (0, 1, 2)), *qkv)
+    _compile_for_chip(_grads(packed_attention_pallas, (0, 1, 2)), *qkv,
+                      names=("packed_attention_fwd", "packed_attention_dq",
+                             "packed_attention_dkv"))
 
 
 def test_packed_attention_prefix_rows_compile(one_chip, pallas_tier):
@@ -122,7 +132,8 @@ def test_decode_attention_compiles(one_chip):
     _compile_for_chip(
         decode_attention_pallas, _spec(one_chip, (B, 1, H, dh)),
         _spec(one_chip, (B, Smax, Hkv, dh)), _spec(one_chip, (B, Smax, Hkv, dh)),
-        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B,), jnp.int32))
+        _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B,), jnp.int32),
+        names=("decode_attention",))
 
 
 @pytest.mark.parametrize("with_reset", [False, True])
@@ -140,7 +151,8 @@ def test_mamba_scan_compiles(one_chip, with_reset):
 
         return _grads(scan, (0, 1, 2, 3, 4))(q, k, v, la, li)
 
-    _compile_for_chip(fwd_bwd, *args)
+    _compile_for_chip(fwd_bwd, *args, names=(
+        "mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"))
 
 
 @pytest.mark.parametrize("mkn", [(4096, 960, 2560), (4096, 2560, 10240),
@@ -154,7 +166,47 @@ def test_quant_matmul_compiles(one_chip, mkn):
 
     _compile_for_chip(fwd_bwd, _spec(one_chip, (M, K)),
                       _spec(one_chip, (K, N), jnp.int8),
-                      _spec(one_chip, (N,), jnp.float32))
+                      _spec(one_chip, (N,), jnp.float32), names=("quant_matmul",))
+
+
+def test_train_step_names_its_kernels_and_phases(one_chip, pallas_tier):
+    """The engine's training step, compiled for the chip, names every
+    kernel it runs in the instruction names the device trace shows, and
+    its phases in the op_name metadata the phase readers join to them
+    (two layers of smollm-360m at full width)."""
+    import re
+
+    from repro.configs import get_config
+    from repro.core import (ExecutionPlanner, ModelGenerator, ParallelismSpec,
+                            PEFTEngine)
+    from repro.data import HTaskLoader, make_task
+    from repro.peft.adapters import LORA
+    from repro.peft.methods import AdapterConfig
+
+    cfg = get_config("smollm-360m").with_overrides(num_layers=2, vocab_size=1024)
+    tasks = [make_task("t0", "sst2", 2, AdapterConfig(
+        LORA, rank=16, targets=("attn_q", "attn_k", "attn_v", "attn_o")), seed=0)]
+    plan = ExecutionPlanner(cfg, ParallelismSpec()).plan(tasks, n_micro=1)
+    gen = ModelGenerator(cfg)
+    gen.register_tasks(tasks)
+    eng = PEFTEngine(gen, plan, lr=1e-3)
+    batch = next(HTaskLoader(tasks, plan.alignment[0], cfg.vocab_size))
+    n_acc = max(len(plan.tasks), sum(eng.reg.mta.kind_capacity.values()))
+    args = (eng.backbone, eng.reg.adapter_params, eng.reg.opt_state,
+            eng._slot_steps, batch, eng._member_ids[0],
+            (jnp.float32(0), jnp.zeros((n_acc,), jnp.float32)))
+    specs = jax.tree.map(lambda a: _spec(one_chip, jnp.shape(a), jnp.result_type(a)),
+                         args)
+    text = eng._make_step(0).lower(*specs).compile().as_text()
+    scopes = set(re.findall(r'op_name="([^"]+)"', text))
+    want = {"packed_attention_fwd", "packed_attention_dq", "packed_attention_dkv",
+            "grouped_lora_fwd", "grouped_lora_bwd"}
+    assert set(re.findall(r'([A-Za-z0-9_]+)\)*/pallas_call"', text)) == want
+    calls = re.findall(r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert {re.sub(r"(\.\d+)+$", "", c) for c in calls} == want
+    assert any("/jvp(train.forward)/" in s for s in scopes)
+    assert any("/transpose(jvp(train.forward))/" in s for s in scopes)
+    assert any("/train.optimizer/" in s for s in scopes)
 
 
 @pytest.fixture
