@@ -155,7 +155,6 @@ def packed_attention(
     prefix_kv: Optional[tuple] = None,   # (pk, pv): [B, P, Hkv, dh] each
     prefix_keep: Optional[jax.Array] = None,  # [B, P] 1.0 = row owns prefix
     block_q: int = 128,
-    block_k: int = 128,
 ) -> jax.Array:
     """Segment-masked flash attention; optionally with learned per-task
     PREFIX k/v rows (soft-prompt PEFT, §3.2).  A prefix row is visible to
@@ -163,7 +162,8 @@ def packed_attention(
     regardless of causal position — iff ``prefix_keep`` gates it on.  On the
     XLA tier the prefix folds into the online-softmax carry init; on the
     Pallas tiers it enters the kernel as extra leading k/v segment rows with
-    wildcard segment ids."""
+    wildcard segment ids, and the kernel's kv tile follows ``block_q``
+    (``kernels.packed_attention.tiles``)."""
     impl = get_impl()
     if impl == "xla":
         from repro.models.attention import flash_attention_pairs
@@ -178,27 +178,23 @@ def packed_attention(
             q, k, v, block=block_q, causal=causal,
             segment_ids=segment_ids, positions=positions, kv_prefix=pref,
         )
-    from repro.kernels.packed_attention import packed_attention_pallas
+    from repro.kernels.packed_attention import (packed_attention_pallas,
+                                                prefix_pad)
 
     interpret = impl == "pallas_interpret"
     if prefix_kv is None:
         return packed_attention_pallas(
             q, k, v, segment_ids=segment_ids, positions=positions,
-            causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret,
+            causal=causal, block_q=block_q, interpret=interpret,
         )
-    import math
-
     B, S = q.shape[0], q.shape[1]
     pk, pv = prefix_kv
     P = pk.shape[1]
     keep = prefix_keep if prefix_keep is not None else jnp.ones(
         (B, P), jnp.float32)
-    # Pad the prefix rows so S + P keeps S's k-tile: an unpadded P (e.g. 16
-    # on S=2048) leaves S + P no lane-aligned divisor and collapses the
-    # k-tile to the whole sequence.  Pad rows are gated off (kseg = -2
-    # matches no query), so they are pure masked work.
-    pad = (-P) % math.gcd(S, block_k)
+    # Pad rows are gated off (kseg = -2 matches no query), so they are
+    # pure masked work.
+    pad = prefix_pad(S, P)
     if pad:
         pk = jnp.pad(pk, ((0, 0), (0, pad), (0, 0), (0, 0)))
         pv = jnp.pad(pv, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -221,7 +217,7 @@ def packed_attention(
     return packed_attention_pallas(
         q, k_full, v_full, segment_ids=segment_ids, positions=positions,
         causal=causal, k_segment_ids=k_segment_ids, k_positions=k_positions,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, interpret=interpret,
     )
 
 

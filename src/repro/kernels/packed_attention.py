@@ -12,6 +12,13 @@ Grid: (batch*heads, n_q, n_k), n_k innermost so the online-softmax scratch
 block still iterates but skips the MXU work, which is the grid-pruning
 analogue of flash attention's triangular traversal.
 
+The KV tile follows the Q tile (:func:`tiles`).  Each grid step pays fixed
+work besides its matmuls — the lane-sparse m/l columns, the acc rescale,
+a cross-lane max and sum, and in dkv a fresh fetch of six q-side blocks —
+so a square tile pays it once per q-tile-wide chunk of keys where a
+128-wide one pays it per 128 keys; a causal S x S call masks the same
+share of square tiles.
+
 Layout: the kernels see head-major ``[B, H, S, dh]`` operands (the wrapper
 transposes the model's ``[B, S, H, dh]``), so every block's last two dims
 are ``(block, dh)`` with ``dh`` the whole array dim — the TPU's (8, 128)
@@ -42,7 +49,8 @@ L = +1e30 so their p underflows to exactly zero in the backward.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +62,28 @@ from repro.kernels.tiling import LANE, SUBLANE, fit_block
 
 NEG_INF = -1e30
 LSE_MASKED = 1e30  # logsumexp sentinel for fully-masked rows
+
+
+def tiles(S: int, Sk: int, block_q: int,
+          block_k: Optional[int] = None) -> Tuple[int, int]:
+    """The (q, kv) tiles of a call with ``S`` queries and ``Sk`` keys.
+
+    q tiles are sublane blocks of the [.., S, dh] operands and columns of
+    the q-side ids; kv tiles are also lane blocks of the k-side id rows.
+    The kv tile is ``block_k`` if given, else the fitted q tile, cut to
+    the largest lane multiple that divides ``Sk`` (or ``Sk`` itself)."""
+    block_q = fit_block(S, block_q, SUBLANE)
+    return block_q, fit_block(Sk, block_q if block_k is None else block_k, LANE)
+
+
+def prefix_pad(S: int, P: int) -> int:
+    """Masked rows to append to ``P`` learned prefix rows so that ``S + P``
+    keeps S's lane-aligned divisors: an unpadded P (e.g. 16 on S=2048)
+    leaves S + P no lane multiple dividing it and collapses the kv tile to
+    the whole sequence.  Padding to the lane, not to the kv tile, keeps the
+    masked work under 128 rows; :func:`tiles` then picks the kv tile that
+    divides ``S + P`` (384 for 1024 + 128)."""
+    return (-P) % math.gcd(S, LANE)
 
 
 def _tile_mask(qpos, kpos, qseg, kseg, causal):
@@ -422,19 +452,17 @@ def packed_attention_pallas(
     k_segment_ids: Optional[jax.Array] = None,  # [B, Sk]; -1 = wildcard row
     k_positions: Optional[jax.Array] = None,    # [B, Sk]
     block_q: int = 128,
-    block_k: int = 128,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Packed flash attention; the k/v sequence may carry ``Sk - S`` extra
     leading rows (learned prefix-tuning k/v) with their own segment ids:
     ``k_segment_ids == -1`` marks a row visible to EVERY query of the batch
-    row, any other negative value a row visible to none."""
+    row, any other negative value a row visible to none.  The kv tile
+    follows the q tile unless ``block_k`` is given (:func:`tiles`)."""
     B, S, H, dh = q.shape
     Sk = k.shape[1]
-    # q tiles are sublane blocks of the [.., S, dh] operands and columns of
-    # the q-side ids; k tiles are also lane blocks of the k-side id rows
-    block_q = fit_block(S, block_q, SUBLANE)
-    block_k = fit_block(Sk, block_k, LANE)
+    block_q, block_k = tiles(S, Sk, block_q, block_k)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     if segment_ids is None:

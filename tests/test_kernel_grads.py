@@ -27,7 +27,8 @@ import pytest
 from repro.kernels import ops as kops
 from repro.kernels.grouped_lora import grouped_lora_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
-from repro.kernels.packed_attention import packed_attention_pallas
+from repro.kernels.packed_attention import (packed_attention_pallas,
+                                            prefix_pad, tiles)
 from repro.kernels.ref import (grouped_lora_ref, mamba_scan_ref,
                                packed_attention_ref)
 
@@ -183,6 +184,7 @@ def test_grouped_lora_ops_impl_parity_under_grad(key):
         (1, 256, 4, 4, 64, 128, 128, True, True),   # packed, 2 segments
         (2, 128, 8, 2, 16, 32, 64, False, False),   # non-causal, G=4
         (2, 128, 2, 1, 32, 128, 32, True, True),    # packed, asymmetric blocks
+        (1, 512, 2, 1, 16, 256, None, True, True),  # kv tile follows q: 256
     ],
 )
 @skip_on_xla
@@ -258,7 +260,17 @@ def test_packed_attention_prefix_rows_grads(key):
     """Learned prefix k/v rows (soft-prompt PEFT): the Pallas wildcard-
     segment path and the XLA carry-init path agree with a dense reference,
     gradients included, and ungated rows' prefixes get exactly zero grad."""
-    B, S, H, Hkv, dh, P = 2, 64, 4, 2, 16, 8
+    _check_prefix_rows(key, B=2, S=64, H=4, Hkv=2, dh=16, P=8, block_q=128)
+
+
+def test_packed_attention_prefix_rows_default_tiles(key):
+    """The same at the model's 512-row q tile, where S + P = 1024 + 128
+    (16 prefix rows padded to the lane) takes a non-square 512 x 384 tile."""
+    assert tiles(1024, 1024 + 16 + prefix_pad(1024, 16), 512) == (512, 384)
+    _check_prefix_rows(key, B=2, S=1024, H=2, Hkv=1, dh=16, P=16, block_q=512)
+
+
+def _check_prefix_rows(key, *, B, S, H, Hkv, dh, P, block_q):
     ks = jax.random.split(key, 6)
     q = jax.random.normal(ks[0], (B, S, H, dh))
     k = jax.random.normal(ks[1], (B, S, Hkv, dh))
@@ -301,7 +313,7 @@ def test_packed_attention_prefix_rows_grads(key):
                 o = kops.packed_attention(q, k, v, segment_ids=seg,
                                           positions=pos, causal=True,
                                           prefix_kv=(pk, pv),
-                                          prefix_keep=keep)
+                                          prefix_keep=keep, block_q=block_q)
                 return (o * g).sum()
 
             gp = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, pk, pv)

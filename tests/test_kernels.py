@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels.grouped_lora import grouped_lora_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
-from repro.kernels.packed_attention import packed_attention_pallas
+from repro.kernels.packed_attention import (packed_attention_pallas, prefix_pad,
+                                            tiles)
 from repro.kernels import ops as kops
 from repro.kernels.ref import grouped_lora_ref, mamba_scan_ref, packed_attention_ref
 from repro.models.ssm import chunked_gla
@@ -63,6 +64,7 @@ def test_grouped_lora_xla_path_matches_ref(key):
         (2, 128, 8, 2, 16, 32, 64, False, False),
         (2, 128, 2, 1, 32, 128, 32, True, True),
         (1, 64, 1, 1, 8, 64, 64, True, False),
+        (1, 512, 2, 1, 16, 256, None, True, True),  # kv tile follows q: 256
     ],
 )
 def test_packed_attention_kernel(dtype, B, S, H, Hkv, dh, bq, bk, causal, packed, key):
@@ -79,6 +81,8 @@ def test_packed_attention_kernel(dtype, B, S, H, Hkv, dh, bq, bk, causal, packed
         pos = jnp.broadcast_to(
             jnp.concatenate([jnp.arange(half), jnp.arange(half)]).astype(jnp.int32), (B, S)
         )
+    if bk is None:
+        assert tiles(S, S, bq) == (bq, bq) and bq != 128
     ref = packed_attention_ref(q, k, v, seg, pos, causal)
     out = packed_attention_pallas(q, k, v, seg, pos, causal, block_q=bq, block_k=bk,
                                   interpret=True)
@@ -86,6 +90,28 @@ def test_packed_attention_kernel(dtype, B, S, H, Hkv, dh, bq, bk, causal, packed
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=tol, atol=tol
     )
+
+
+@pytest.mark.parametrize("S", [64, 200, 256, 1024, 4096])
+@pytest.mark.parametrize("block_q", [128, 512])
+@pytest.mark.parametrize("P", [0, 16, 128])
+def test_packed_attention_tile_rule(S, block_q, P):
+    """The kv tile divides Sk, is a lane multiple or all of Sk, is the q
+    tile on an S x S call, and prefix rows pad to the lane, not the tile."""
+    pad = prefix_pad(S, P) if P else 0
+    Sk = S + P + pad
+    bq, bk = tiles(S, Sk, block_q)
+    assert S % bq == 0 and bq <= max(block_q, S)
+    assert Sk % bk == 0 and (bk % 128 == 0 or bk == Sk)
+    if S % 128 == 0:
+        assert bq == min(block_q, S)
+        assert (P + pad) % 128 == 0 and pad < 128
+        if Sk == S:
+            assert bk == bq
+        else:
+            assert bk % 128 == 0 and bk <= bq
+    # an explicit kv tile is still fitted to Sk
+    assert tiles(S, Sk, block_q, 128)[1] == (128 if Sk % 128 == 0 else Sk)
 
 
 def test_flash_pairs_matches_dense_ref(key):

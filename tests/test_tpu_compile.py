@@ -20,7 +20,8 @@ from repro.kernels import ops as kops
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.grouped_lora import grouped_lora_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
-from repro.kernels.packed_attention import packed_attention_pallas
+from repro.kernels.packed_attention import (packed_attention_pallas,
+                                            prefix_pad, tiles)
 from repro.kernels.quant_matmul import quant_matmul_pallas
 from repro.launch import compile_cache
 
@@ -125,6 +126,37 @@ def test_packed_attention_prefix_rows_compile(one_chip, pallas_tier):
         attn, _spec(one_chip, (B, S, H, dh)), _spec(one_chip, (B, S, Hkv, dh)),
         _spec(one_chip, (B, S, Hkv, dh)), _spec(one_chip, (B, P, Hkv, dh)),
         _spec(one_chip, (B, P, Hkv, dh)), _spec(one_chip, (B, P), jnp.float32))
+
+
+@pytest.mark.parametrize("B,S,H,Hkv,dh,P,kv_tile", [
+    (16, 1024, 15, 5, 64, 0, 512),   # smollm-360m's training step
+    (16, 1024, 15, 5, 64, 16, 384),  # with 16 prefix-tuning rows
+    (1, 4096, 32, 8, 128, 0, 512),   # long and wide: 32/8 heads of 128
+])
+def test_packed_attention_train_tiles_compile(one_chip, pallas_tier, B, S, H,
+                                              Hkv, dh, P, kv_tile):
+    """fwd, dq and dkv at the model's 512-row q tile: the kv tile follows
+    it, and the (512 x 512) f32 temporaries fit the default scoped VMEM."""
+    block_q = 512
+    assert tiles(S, S + P + (prefix_pad(S, P) if P else 0), block_q) == (
+        block_q, kv_tile)
+    names = ("packed_attention_fwd", "packed_attention_dq",
+             "packed_attention_dkv")
+    qkv = (_spec(one_chip, (B, S, H, dh)), _spec(one_chip, (B, S, Hkv, dh)),
+           _spec(one_chip, (B, S, Hkv, dh)))
+    if not P:
+        _compile_for_chip(_grads(lambda q, k, v: kops.packed_attention(
+            q, k, v, block_q=block_q), (0, 1, 2)), *qkv, names=names)
+        return
+
+    def attn(q, k, v, pk, pv, keep):
+        return _grads(lambda q, k, v, pk, pv: kops.packed_attention(
+            q, k, v, prefix_kv=(pk, pv), prefix_keep=keep, block_q=block_q),
+            (0, 1, 2, 3, 4))(q, k, v, pk, pv)
+
+    _compile_for_chip(attn, *qkv, _spec(one_chip, (B, P, Hkv, dh)),
+                      _spec(one_chip, (B, P, Hkv, dh)),
+                      _spec(one_chip, (B, P), jnp.float32), names=names)
 
 
 def test_decode_attention_compiles(one_chip):
