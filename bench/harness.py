@@ -6,8 +6,12 @@ window, the check phase).
 A later cell, configuration, traffic mix or metric is added by new files
 and entries alone:
 
-* ``bench/configs/<config>.json``  sizes, source, cuts, and the program's
-  architecture id with the overrides that make it run those sizes;
+* ``bench/configs/<config>.json``  sizes, source, cuts, the block family,
+  and the program's architecture id with the overrides that make it run
+  those sizes;
+* ``bench/blocks/<family>.py``     what depends on the block: the program
+  config check, sizes and sites, weights, adapter layout, the reference's
+  block and the work counts (``bench/blocks/__init__.py``);
 * ``bench/traffic/<traffic>.json`` tenants, their frozen lengths, the
   service settings;
 * ``bench/limits/<cell>.json``     the limit of every number compared;
@@ -25,6 +29,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
+import blocks
 import traffic as tr
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -61,9 +66,11 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     confs = {c["name"]: c for c in spec["configs"]}
     conf = confs[w["config"]]
     bench = root / "bench"
+    cfg = load_json(root / conf["file"])
+    cfg[blocks.DIR_KEY] = str(bench / "blocks")
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        traffic_name=w["traffic"], cfg=load_json(root / conf["file"]),
+        traffic_name=w["traffic"], cfg=cfg,
         traffic=load_json(bench / "traffic" / f"{w['traffic']}.json"),
         limits=load_json(bench / "limits" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
@@ -84,29 +91,10 @@ def load_metric(name: str, root: Path = ROOT):
 
 
 def arch_config(cfg: Dict):
-    """The program's ``ArchConfig`` for a configuration file, checked
-    against the file's sizes so that the program runs what the file says."""
-    from repro.configs import get_config
-
-    prog = cfg["program"]
-    ac = get_config(prog["arch"]).with_overrides(**prog.get("overrides", {}))
-    want = {
-        "num_layers": cfg["num_hidden_layers"], "d_model": cfg["hidden_size"],
-        "num_heads": cfg["num_attention_heads"],
-        "num_kv_heads": cfg["num_key_value_heads"],
-        "d_ff": cfg["intermediate_size"], "vocab_size": cfg["vocab_size"],
-        "tie_embeddings": cfg["tie_word_embeddings"],
-        "gated_mlp": cfg["block"]["mlp"] == "swiglu",
-        "attention_bias": cfg["block"]["attention_bias"],
-        "rope_theta": float(cfg["rope_theta"]),
-        "norm_eps": float(cfg.get("rms_norm_eps", cfg.get("norm_epsilon"))),
-    }
-    got = {k: getattr(ac, k) for k in want}
-    got["head_dim"] = ac.resolved_head_dim()
-    want["head_dim"] = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    if got != want or ac.family != "dense" or ac.qk_norm:
-        raise RuntimeError(f"program config {got} does not run the file's {want}")
-    return ac
+    """The program's ``ArchConfig`` for a configuration file, checked by its
+    block family against the file's sizes so that the program runs what the
+    file says."""
+    return blocks.family(cfg).program_config(cfg)
 
 
 def program_task(t: tr.Tenant):
@@ -172,7 +160,7 @@ def build(cell: Cell, seed: int) -> Run:
     svc = MuxTuneService(
         ac, ParallelismSpec(), lr=float(cell.traffic["lr"]), seed=seed,
         admission=AdmissionConfig(**cell.traffic["service"]))
-    prog_bb = wt.program_backbone(bb)
+    prog_bb = wt.program_backbone(cell.cfg, bb)
     wt.same_layout(prog_bb, svc.gen.model.abstract_params(), "backbone")
     svc.gen.backbone_params = prog_bb
     for t in tenants:
@@ -182,7 +170,7 @@ def build(cell: Cell, seed: int) -> Run:
     kind_rank = svc.engine.reg.mta.kind_rank
     prefix_rows = int(kind_rank.get("prefix", 0))
     adapters = wt.make_adapters(cell.cfg, tenants, seed, prefix_rows)
-    wt.install_adapters(svc, tenants, adapters)
+    wt.install_adapters(cell.cfg, svc, tenants, adapters)
     # the reference's copy lives on the host: the chip's memory is the
     # program's
     adapters = jax.device_get(adapters)
@@ -196,16 +184,18 @@ def tenant_slices(run: Run, tree) -> List[Dict]:
 
     import weights as wt
 
+    cfg = run.cell.cfg
+    fam = blocks.family(cfg)
     slots = wt.tenant_slots(run.svc, run.tenants)
-    shapes = [wt.adapter_shapes(run.cell.cfg, t, run.prefix_rows)
-              for t in run.tenants]
+    shapes = [wt.adapter_shapes(cfg, t, run.prefix_rows) for t in run.tenants]
     kinds = [t.method for t in run.tenants]
 
     def take(tree):
+        stacks = fam.from_program(cfg, tree)      # [L, cap, ...] per site
         out = []
         for kind, slot, shp in zip(kinds, slots, shapes):
-            out.append({site: {leaf: tree[kind][site][leaf][:, slot][
-                (slice(None),) + tuple(slice(0, s) for s in leaves[leaf])]
+            out.append({site: {leaf: stacks[kind][site][leaf][:, slot][
+                (slice(None),) + tuple(slice(0, s) for s in leaves[leaf][1:])]
                 .astype(np.float32) for leaf in leaves}
                 for site, leaves in shp.items()})
         return out

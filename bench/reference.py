@@ -1,27 +1,28 @@
-"""Plain reference of the dense decoder and its PEFT methods, in float32.
+"""Plain reference of the model's block and its PEFT methods, in float32.
 
 Straightforward ``jax.numpy``: no kernels, no packing, no fused batches, no
 grouped adapters, no cache.  Every tenant is computed on its own, one
 sequence per row; each sequence attends only to its own earlier tokens
-(and to its tenant's prefix rows).  Layers run under ``lax.scan`` with the
-layer's bfloat16 weights upcast to float32 inside the step, so a wide
-model fits beside its own weights.  Matrix products use
-``Precision.HIGHEST`` (a float32 product on a TPU otherwise runs in
-bfloat16 passes).
+(and to its tenant's prefix rows).  The block stack is the configuration's
+block family's (``bench/blocks/<family>.py``: ``stack``), built from the
+generic parts here; layers run under ``lax.scan`` with the layer's bfloat16
+weights upcast to float32 inside the step, so a wide model fits beside its
+own weights.  Matrix products use ``Precision.HIGHEST`` (a float32 product
+on a TPU otherwise runs in bfloat16 passes).
 
 ``lower`` makes the control: every operand of every matrix product is
 rounded to float8 (e4m3) with a per-tensor absmax scale, the step below the
 bfloat16 that the configurations state.
 
-The block follows the configuration file: RMSNorm, rotary embeddings on the
-two halves of each head, grouped-query causal attention, SwiGLU or tanh-GELU
-MLP, optional attention biases, tied or untied LM head.  The methods:
+The generic parts: RMSNorm, rotary embeddings on the two halves of each
+head, and the methods at a matmul site:
 
 * LoRA at a site: ``y += (alpha / r) * (x a) b``;
 * bottleneck adapter at a site: ``y += gelu(y down) up``, on the site's
   output (bias included);
 * prefix tuning: learned post-rotary key/value rows that every token of the
-  tenant's sequences attends to, ahead of its causal context.
+  tenant's sequences attends to, ahead of its causal context (the family's
+  attention takes them).
 
 The optimizer is AdamW with the hyperparameters the caller passes (the
 ones the program under test states), bias-corrected at each tenant's own
@@ -37,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from work import dims
+import blocks
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -73,10 +74,9 @@ class Reference:
 
     def __init__(self, cfg: Dict, lower: bool = False):
         self.cfg = cfg
-        self.n = dims(cfg)
+        self.block = blocks.family(cfg)
+        self.n = self.block.dims(cfg)
         self.eps = float(cfg.get("rms_norm_eps", cfg.get("norm_epsilon")))
-        self.theta = float(cfg["rope_theta"])
-        self.swiglu = cfg["block"]["mlp"] == "swiglu"
         self.lower = lower
 
     def _mm(self, spec: str, a, b):
@@ -92,7 +92,8 @@ class Reference:
     def _rope(self, x, pos):
         """x [N, S, H, dh] float32, pos [N, S]."""
         dh = x.shape[-1]
-        inv = 1.0 / (self.theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
+        theta = float(self.cfg["rope_theta"])
+        inv = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
         ang = pos[..., None].astype(jnp.float32) * inv
         cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
         x1, x2 = jnp.split(x, 2, axis=-1)
@@ -119,80 +120,9 @@ class Reference:
             add = self._mm("nsr,ro->nso", h, p["up"])
         return (yf + add).reshape(y.shape)
 
-    def _layer(self, x, lw, ad, pos, kmask, method, scale, prefix):
-        """One block over ``x [N, S, d]``.  ``kmask [N, S]`` marks the real
-        tokens (keys); attention is causal within each row.  ``prefix`` is
-        this layer's (pk, pv) [P, kv] or None."""
-        n = self.n
-        N, S, _ = x.shape
-        h = self._rms(x, lw["ln1"])
-        q = self._mm("nsd,dhk->nshk", h, lw["wq"])
-        k = self._mm("nsd,dhk->nshk", h, lw["wk"])
-        v = self._mm("nsd,dhk->nshk", h, lw["wv"])
-        if "bq" in lw:
-            q = q + lw["bq"].astype(jnp.float32)
-            k = k + lw["bk"].astype(jnp.float32)
-            v = v + lw["bv"].astype(jnp.float32)
-        q = self._site(ad, "attn_q", h, q, method, scale)
-        k = self._site(ad, "attn_k", h, k, method, scale)
-        v = self._site(ad, "attn_v", h, v, method, scale)
-        q, k = self._rope(q, pos), self._rope(k, pos)
-        G = n["H"] // n["Hkv"]
-        qg = q.reshape(N, S, n["Hkv"], G, n["dh"])
-        s = self._mm("nskgd,ntkd->nkgst", qg, k) / np.sqrt(n["dh"])
-        causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-        allow = causal[None] & kmask[:, None, :]
-        s = jnp.where(allow[:, None, None], s, -jnp.inf)
-        if prefix is not None:
-            P = prefix[0].shape[0]
-            pk = prefix[0].reshape(P, n["Hkv"], n["dh"])
-            pv = prefix[1].reshape(P, n["Hkv"], n["dh"])
-            sp = self._mm("nskgd,pkd->nkgsp", qg, pk) / np.sqrt(n["dh"])
-            w = jax.nn.softmax(jnp.concatenate([sp, s], axis=-1), axis=-1)
-            o = (self._mm("nkgst,ntkd->nskgd", w[..., P:], v)
-                 + self._mm("nkgsp,pkd->nskgd", w[..., :P], pv))
-        else:
-            w = jax.nn.softmax(s, axis=-1)
-            o = self._mm("nkgst,ntkd->nskgd", w, v)
-        o = o.reshape(N, S, n["H"] * n["dh"])
-        y = self._mm("nsq,qd->nsd", o, lw["wo"].reshape(n["H"] * n["dh"], -1))
-        if "bo" in lw:
-            y = y + lw["bo"].astype(jnp.float32)
-        y = self._site(ad, "attn_o", o, y, method, scale)
-        x = x + y
-        h = self._rms(x, lw["ln2"])
-        if self.swiglu:
-            g = self._site(ad, "mlp_gate", h, self._mm("nsd,df->nsf", h, lw["w_gate"]),
-                           method, scale)
-            u = self._site(ad, "mlp_up", h, self._mm("nsd,df->nsf", h, lw["w_up"]),
-                           method, scale)
-            m = jax.nn.silu(g) * u
-            y = self._site(ad, "mlp_down", m, self._mm("nsf,fd->nsd", m, lw["w_down"]),
-                           method, scale)
-        else:
-            f = self._site(ad, "mlp_fc1", h, self._mm("nsd,df->nsf", h, lw["w_fc1"]),
-                           method, scale)
-            m = jax.nn.gelu(f, approximate=True)
-            y = self._site(ad, "mlp_fc2", m, self._mm("nsf,fd->nsd", m, lw["w_fc2"]),
-                           method, scale)
-        return x + y
-
     def _stack(self, bb, x, ad, pos, kmask, method, scale):
-        site_ad = {k: v for k, v in ad.items() if k != "attn_prefix"}
-        prefix = None
-        if "attn_prefix" in ad:
-            prefix = (ad["attn_prefix"]["pk"], ad["attn_prefix"]["pv"])
-
-        @jax.checkpoint
-        def body(x, xs):
-            lw, a, pre = xs
-            return self._layer(x, lw, a, pos, kmask, method, scale, pre), None
-
-        x, _ = jax.lax.scan(body, x, (bb["layers"], site_ad, prefix))
-        x = self._rms(x, bb["final_norm"])
-        if "unembed" in bb:
-            return self._mm("nsd,dv->nsv", x, bb["unembed"])
-        return self._mm("nsd,vd->nsv", x, bb["tok"])
+        """Logits of the configuration's block stack over ``x [N, S, d]``."""
+        return self.block.stack(self, bb, x, ad, pos, kmask, method, scale)
 
     # ------------------------------------------------------------------
     # training: one tenant's loss and gradient

@@ -52,13 +52,18 @@ def test_a_metric_reader_is_found_by_its_name():
 def test_program_config_must_run_the_files_sizes():
     cell = harness.find_cell("tiny.train", FIXTURE)
     assert harness.arch_config(cell.cfg).d_model == 64
-    bad = json.loads(json.dumps(cell.cfg))
-    bad["program"]["overrides"]["d_ff"] = 96
-    try:
-        harness.arch_config(bad)
-    except RuntimeError:
-        return
-    raise AssertionError("a program config that differs from the file ran")
+    wider = json.loads(json.dumps(cell.cfg))
+    wider["program"]["overrides"]["d_ff"] = 96
+    # the file's dense block, the program's hybrid family at the file's sizes
+    other_family = json.loads(json.dumps(cell.cfg))
+    other_family["program"]["arch"] = "zamba2-2.7b"
+    for bad in (wider, other_family):
+        try:
+            harness.arch_config(bad)
+        except RuntimeError:
+            continue
+        raise AssertionError(f"a program config that differs from the file "
+                             f"ran: {bad['program']}")
 
 
 def test_run_refuses_without_a_tpu():

@@ -10,7 +10,7 @@ from traffic import Tenant  # noqa: E402
 
 CFG = {"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2,
        "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 4,
-       "vocab_size": 32, "block": {"mlp": "swiglu"}}
+       "vocab_size": 32, "block": {"family": "dense", "mlp": "swiglu"}}
 
 
 def tenant(method, rank, lengths, targets=("attn_q",)):

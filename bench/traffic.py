@@ -20,6 +20,8 @@ from typing import Dict, Iterator, List
 
 import numpy as np
 
+import blocks
+
 #: pad length, mean and std of the true length as fractions of the pad
 #: (``repro.data.synthetic.DATASETS``: SST-2, OpenBookQA, RTE)
 NORMAL_PROFILES = {
@@ -27,8 +29,6 @@ NORMAL_PROFILES = {
     "qa": (128, 0.60, 0.22),
     "rte": (256, 0.50, 0.25),
 }
-
-ATTN_TARGETS = ("attn_q", "attn_k", "attn_v", "attn_o")
 
 
 def frozen_lengths(profile: Dict, n: int, seed: int) -> List[int]:
@@ -86,20 +86,14 @@ class Tenant:
         return self.alpha / self.rank
 
 
-def mlp_targets(cfg: Dict) -> tuple:
-    if cfg["block"]["mlp"] == "swiglu":
-        return ("mlp_gate", "mlp_up", "mlp_down")
-    return ("mlp_fc1", "mlp_fc2")
-
-
 def tenants(traffic: Dict, cfg: Dict) -> List[Tenant]:
-    """The traffic file's tenant table, resolved against a configuration
-    (``"all"`` targets add every MLP matmul the configuration has)."""
+    """The traffic file's tenant table, resolved against a configuration:
+    a tenant's ``"targets"`` group (``"attn"`` where it names none) is the
+    sites the configuration's block family gives that group."""
+    fam = blocks.family(cfg)
     out = []
     for t in traffic["tenants"]:
-        targets = ATTN_TARGETS
-        if t.get("targets") == "all":
-            targets = ATTN_TARGETS + mlp_targets(cfg)
+        targets = fam.targets(cfg, t.get("targets", "attn"))
         prof = traffic["length_profiles"][t["lengths"]]
         out.append(Tenant(
             tenant_id=t["id"], method=t["method"], rank=int(t["rank"]),

@@ -16,39 +16,27 @@ Conventions (per layer unless said otherwise):
   (scores and the weighted sum) and twice that backward;
 * an adapter counts its own tenant's rank: forward, activation gradient and
   its own weight gradient (3x the forward).
+
+Each block family counts its own matmuls and attention by these
+conventions (``bench/blocks/<family>.py``); the counts here dispatch on the
+configuration's family.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Sequence
+from typing import Dict, Iterable
 
-def dims(cfg: Dict) -> Dict[str, int]:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "d": d, "H": h,
-            "Hkv": cfg["num_key_value_heads"],
-            "dh": cfg.get("head_dim") or d // h,
-            "F": cfg["intermediate_size"], "V": cfg["vocab_size"]}
+import blocks
 
 
 def site_dims(cfg: Dict) -> Dict[str, tuple]:
     """(d_in, d_out) of every matmul an adapter can attach to."""
-    n = dims(cfg)
-    q, kv = n["H"] * n["dh"], n["Hkv"] * n["dh"]
-    out = {"attn_q": (n["d"], q), "attn_k": (n["d"], kv),
-           "attn_v": (n["d"], kv), "attn_o": (q, n["d"])}
-    if cfg["block"]["mlp"] == "swiglu":
-        out.update({"mlp_gate": (n["d"], n["F"]), "mlp_up": (n["d"], n["F"]),
-                    "mlp_down": (n["F"], n["d"])})
-    else:
-        out.update({"mlp_fc1": (n["d"], n["F"]), "mlp_fc2": (n["F"], n["d"])})
-    return out
+    return blocks.family(cfg).site_dims(cfg)
 
 
 def matmul_params(cfg: Dict) -> int:
-    """Weights every token is multiplied by: the layers' projections and
-    MLP, and the LM head (the tied embedding when the head is tied)."""
-    n = dims(cfg)
-    per_layer = sum(i * o for i, o in site_dims(cfg).values())
-    return n["L"] * per_layer + n["d"] * n["V"]
+    """Weights every token is multiplied by, over all layers and the LM
+    head."""
+    return blocks.family(cfg).matmul_params(cfg)
 
 
 def attention_pairs(lengths: Iterable[int], prefix_rows: int = 0) -> int:
@@ -57,30 +45,7 @@ def attention_pairs(lengths: Iterable[int], prefix_rows: int = 0) -> int:
     return sum(l * (l + 1) // 2 + l * prefix_rows for l in lengths)
 
 
-def adapter_flops_fwd(cfg: Dict, method: str, rank: int,
-                      targets: Sequence[str]) -> float:
-    """Forward FLOPs per token of one tenant's adapter over all layers
-    (prefix rows are counted as attention pairs instead)."""
-    if method == "prefix":
-        return 0.0
-    sd = site_dims(cfg)
-    per = 0.0
-    for s in targets:
-        din, dout = sd[s]
-        per += 2.0 * rank * (din + dout) if method == "lora" else 4.0 * rank * dout
-    return dims(cfg)["L"] * per
-
-
 def train_flops(cfg: Dict, tenants) -> float:
     """Required FLOPs of one training iteration: every tenant's frozen
     lengths (one micro-batch each), forward plus backward."""
-    n = dims(cfg)
-    attn_pair = 4.0 * n["dh"] * n["H"] * n["L"]
-    total = 0.0
-    for t in tenants:
-        toks = sum(t.lengths)
-        prefix = t.rank if t.method == "prefix" else 0
-        total += 4.0 * matmul_params(cfg) * toks
-        total += 3.0 * attn_pair * attention_pairs(t.lengths, prefix)
-        total += 3.0 * adapter_flops_fwd(cfg, t.method, t.rank, t.targets) * toks
-    return total
+    return blocks.family(cfg).train_flops(cfg, tenants)
