@@ -107,12 +107,13 @@ def _bench_packed_attention(rows: list[str]) -> None:
             kops.set_impl(impl)
             try:
                 fwd = jax.jit(lambda q, k, v: kops.packed_attention(
-                    q, k, v, segment_ids=seg, positions=pos, causal=True))
+                    q, k, v, segment_ids=seg, positions=pos, causal=True,
+                    scale=dh ** -0.5))
 
                 def loss(q, k, v):
                     return (kops.packed_attention(
-                        q, k, v, segment_ids=seg, positions=pos, causal=True
-                    ) * g).sum()
+                        q, k, v, segment_ids=seg, positions=pos, causal=True,
+                        scale=dh ** -0.5) * g).sum()
 
                 bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
                 fwd(q, k, v).block_until_ready()

@@ -111,7 +111,7 @@ def analytic_memory(cfg, shape, chips: int, tp: int, dp: int) -> Dict[str, float
     else:
         dh = cfg.resolved_head_dim()
         if cfg.attention != "none":
-            n_kv_layers = (cfg.num_layers // cfg.hybrid_period
+            n_kv_layers = (cfg.layers_of("attention")
                            if cfg.family == "hybrid" else cfg.num_layers)
             cache = (n_kv_layers * shape.global_batch * shape.seq_len *
                      cfg.num_kv_heads * dh * 2 * 2.0) / chips
@@ -119,8 +119,8 @@ def analytic_memory(cfg, shape, chips: int, tp: int, dp: int) -> Dict[str, float
             d_in = cfg.ssm_expand * cfg.d_model
             nh = d_in // cfg.ssm_head_dim if cfg.family == "hybrid" else cfg.num_heads
             st = cfg.ssm_state if cfg.family == "hybrid" else (d_in // cfg.num_heads)
-            n_ssm = cfg.num_layers - (cfg.num_layers // cfg.hybrid_period
-                                      if cfg.family == "hybrid" else 0)
+            n_ssm = (cfg.layers_of("mamba") if cfg.family == "hybrid"
+                     else cfg.num_layers)
             cache += n_ssm * shape.global_batch * nh * st * (
                 cfg.ssm_head_dim if cfg.family == "hybrid" else st + 1) * 4.0 / min(chips, tp * dp)
     return {"params_dev": params_dev, "act_dev": act, "cache_dev": cache,
@@ -151,7 +151,7 @@ def tpu_memory_bytes(cfg, shape, chips: int, tp: int) -> float:
     dh = cfg.resolved_head_dim()
     cache = 0.0
     if cfg.attention != "none":
-        n_kv = (cfg.num_layers // cfg.hybrid_period
+        n_kv = (cfg.layers_of("attention")
                 if cfg.family == "hybrid" else cfg.num_layers)
         cache = n_kv * shape.global_batch * shape.seq_len * cfg.num_kv_heads * dh * 2 * 2.0 / chips
     return p_bytes + cache

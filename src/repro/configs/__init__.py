@@ -11,6 +11,11 @@ import importlib
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+import numpy as np
+
+#: taps of the Mamba-2 depthwise causal convolution
+CONV_W = 4
+
 # ---------------------------------------------------------------------------
 # Shape registry (assigned: 4 shapes per LM arch)
 # ---------------------------------------------------------------------------
@@ -78,9 +83,12 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
-    # hybrid: layers per super-block and which index inside is attention.
-    hybrid_period: int = 0  # e.g. 6 -> every 6th layer is (shared) attention
-    shared_attention: bool = False  # zamba2-style weight-shared attn block
+    # hybrid: one period of layer kinds ("mamba" | "attention"), repeated
+    # num_layers / len(layer_pattern) times.
+    layer_pattern: Tuple[str, ...] = ()
+    # zamba2: every attention layer runs one weight-shared attention+MLP block
+    shared_attention: bool = False
+    ssm_conv_bias: bool = False
     # xLSTM-style pattern: number of mLSTM layers per sLSTM layer (0 = none)
     slstm_period: int = 0
 
@@ -90,6 +98,7 @@ class ArchConfig:
     max_source_positions: int = 0  # whisper: 1500 frames
 
     # --- positional / misc ---
+    positional: str = "rope"  # "rope" | "nope" (attention sees no positions)
     rope_theta: float = 10_000.0
     mrope: bool = False  # qwen2-vl 3-section multimodal RoPE
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
@@ -101,6 +110,12 @@ class ArchConfig:
 
     # --- attention kind: "full" | "none" (pure recurrent) ---
     attention: str = "full"
+    attention_scale: float = 0.0  # softmax scale; 0 -> 1 / sqrt(head_dim)
+
+    # --- granite-style multipliers (1.0 = none) ---
+    embedding_multiplier: float = 1.0  # scales the token embeddings
+    residual_multiplier: float = 1.0  # scales each sublayer's residual branch
+    logits_scaling: float = 1.0  # the logits are divided by it
 
     # --- dtype / execution knobs ---
     param_dtype: str = "bfloat16"
@@ -118,11 +133,31 @@ class ArchConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads)
 
+    def softmax_scale(self) -> float:
+        """The attention softmax scale: ``attention_scale`` where the
+        configuration gives one, else ``1 / sqrt(head_dim)``."""
+        return self.attention_scale or 1.0 / np.sqrt(self.resolved_head_dim())
+
     def backbone_dtype_bytes(self) -> int:
         """Bytes per resident backbone weight — the precision axis of the
         cost model (Eq. 5 / bandwidth terms) and of admission packing."""
         return {"int8": 1, "float8": 1, "bfloat16": 2, "float16": 2,
                 "float32": 4}[self.backbone_dtype]
+
+    @property
+    def n_periods(self) -> int:
+        """Repeats of ``layer_pattern`` (hybrid)."""
+        return self.num_layers // len(self.layer_pattern)
+
+    @property
+    def mamba_mlp(self) -> bool:
+        """Each Mamba layer carries its own MLP (hybrid) unless the MLP sits
+        in zamba2's weight-shared attention block."""
+        return not self.shared_attention
+
+    def layers_of(self, kind: str) -> int:
+        """Layers of one kind over the whole depth (hybrid)."""
+        return self.n_periods * self.layer_pattern.count(kind)
 
     @property
     def q_dim(self) -> int:
@@ -166,27 +201,25 @@ class ArchConfig:
                 + n_norms
             )
             total = self.num_layers * per_layer
-        elif self.family in ("hybrid", "ssm"):
+        elif self.family == "hybrid":
             d_in = self.ssm_expand * d
-            n_ssm = d * (2 * d_in + 2 * self.num_heads * 0)  # in-proj(x,z)
-            n_ssm += d_in * (2 * self.ssm_state)  # B,C projections
-            n_ssm += d_in  # dt
-            n_ssm += d_in * d  # out proj
-            per_ssm = n_ssm + n_norms
-            if self.family == "hybrid":
-                n_attn_layers = (
-                    self.num_layers // self.hybrid_period if self.hybrid_period else 0
-                )
-                n_ssm_layers = self.num_layers - n_attn_layers
-                attn_copies = 1 if self.shared_attention else n_attn_layers
-                # Mamba blocks carry no separate MLP; the (shared) attention
-                # block includes its own MLP.
-                total = (
-                    n_ssm_layers * per_ssm
-                    + attn_copies * (n_attn + n_mlp_dense + n_norms)
-                )
-            else:  # ssm (xlstm): mLSTM/sLSTM projections, no d_ff MLP
-                total = self.num_layers * (n_attn + n_norms)
+            nh = d_in // self.ssm_head_dim
+            st = self.ssm_state
+            n_ssm = d * (2 * d_in + 2 * st + nh)  # in-proj: z, x, B, C, dt
+            n_ssm += CONV_W * (d_in + 2 * st)  # depthwise conv over x, B, C
+            if self.ssm_conv_bias:
+                n_ssm += d_in + 2 * st
+            n_ssm += 3 * nh  # dt bias, A, D
+            n_ssm += d_in  # gated RMSNorm
+            n_ssm += d_in * d  # out-proj
+            per_ssm = n_ssm + d
+            if self.mamba_mlp:
+                per_ssm += n_mlp_dense + d
+            attn_copies = 1 if self.shared_attention else self.layers_of("attention")
+            total = (self.layers_of("mamba") * per_ssm
+                     + attn_copies * (n_attn + n_mlp_dense + n_norms))
+        elif self.family == "ssm":  # xlstm: mLSTM/sLSTM projections, no d_ff MLP
+            total = self.num_layers * (n_attn + n_norms)
         elif self.family == "audio":
             enc = self.num_encoder_layers * (n_attn + n_mlp_dense + n_norms)
             dec = self.num_layers * (2 * n_attn + n_mlp_dense + 3 * d)
@@ -209,6 +242,7 @@ _REGISTRY = {
     "starcoder2-7b": "starcoder2_7b",
     "smollm-360m": "smollm_360m",
     "zamba2-2.7b": "zamba2_2_7b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "xlstm-1.3b": "xlstm_1_3b",
     "whisper-large-v3": "whisper_large_v3",
 }
@@ -243,8 +277,8 @@ def smoke_config(name: str) -> ArchConfig:
                     num_shared_experts=min(cfg.num_shared_experts, 1))
     if cfg.family in ("hybrid", "ssm"):
         over.update(ssm_state=8, ssm_head_dim=16, ssm_chunk=16, num_layers=4)
-        if cfg.hybrid_period:
-            over.update(hybrid_period=2)
+        if cfg.layer_pattern:
+            over.update(layer_pattern=("mamba", "attention"))
         if cfg.slstm_period:
             over.update(slstm_period=2)
     if cfg.is_encoder_decoder:

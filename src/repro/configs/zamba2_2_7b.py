@@ -1,8 +1,8 @@
 """zamba2-2.7b [hybrid] — Mamba2 + shared attn blocks [arXiv:2411.15242; hf].
 
-54 layers in super-blocks of ``hybrid_period``: 5 Mamba2 blocks followed by
-one application of a single weight-shared attention+MLP block (zamba2's
-shared transformer block).
+54 layers in nine periods of ``layer_pattern``: 5 MLP-less Mamba2 layers
+followed by one application of a single weight-shared attention+MLP block
+(zamba2's shared transformer block).
 """
 from repro.configs import ArchConfig
 
@@ -20,7 +20,7 @@ CONFIG = ArchConfig(
     ssm_head_dim=64,
     ssm_expand=2,
     ssm_chunk=256,
-    hybrid_period=6,  # every 6th layer = shared attention block
+    layer_pattern=("mamba",) * 5 + ("attention",),
     shared_attention=True,
     rope_theta=10_000.0,
 )

@@ -32,7 +32,9 @@ def _group_depths(cfg: ArchConfig) -> Dict[str, int]:
     if cfg.family in ("dense", "vlm", "moe", "audio"):
         return {"": 1}
     if cfg.family == "hybrid":
-        return {"mamba": 2, "shared_attn": 0}
+        if cfg.shared_attention:
+            return {"mamba": 2, "shared_attn": 0}
+        return {"mamba": 2, "attn": 2}
     if cfg.family == "ssm":
         return {"mlstm": 2, "slstm": 1}
     raise ValueError(cfg.family)

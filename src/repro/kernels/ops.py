@@ -155,6 +155,7 @@ def packed_attention(
     prefix_kv: Optional[tuple] = None,   # (pk, pv): [B, P, Hkv, dh] each
     prefix_keep: Optional[jax.Array] = None,  # [B, P] 1.0 = row owns prefix
     block_q: int = 128,
+    scale: float,  # softmax scale (``ArchConfig.softmax_scale``)
 ) -> jax.Array:
     """Segment-masked flash attention; optionally with learned per-task
     PREFIX k/v rows (soft-prompt PEFT, §3.2).  A prefix row is visible to
@@ -177,6 +178,7 @@ def packed_attention(
         return flash_attention_pairs(
             q, k, v, block=block_q, causal=causal,
             segment_ids=segment_ids, positions=positions, kv_prefix=pref,
+            scale=scale,
         )
     from repro.kernels.packed_attention import (packed_attention_pallas,
                                                 prefix_pad)
@@ -185,7 +187,7 @@ def packed_attention(
     if prefix_kv is None:
         return packed_attention_pallas(
             q, k, v, segment_ids=segment_ids, positions=positions,
-            causal=causal, block_q=block_q, interpret=interpret,
+            causal=causal, block_q=block_q, interpret=interpret, scale=scale,
         )
     B, S = q.shape[0], q.shape[1]
     pk, pv = prefix_kv
@@ -217,7 +219,7 @@ def packed_attention(
     return packed_attention_pallas(
         q, k_full, v_full, segment_ids=segment_ids, positions=positions,
         causal=causal, k_segment_ids=k_segment_ids, k_positions=k_positions,
-        block_q=block_q, interpret=interpret,
+        block_q=block_q, interpret=interpret, scale=scale,
     )
 
 

@@ -31,13 +31,14 @@ of ``(block_q, 1)`` against ``(1, block_k)``.
 Differentiable via ``jax.custom_vjp`` (flash-attention backward).  The
 forward under autodiff additionally emits the per-row logsumexp
 L = m + log(l) ([B, H, S, 1] f32), so the backward never materializes the
-[S, S] probability matrix: each tile recomputes p = exp(q k^T / sqrt(d) - L)
-from the saved L.  Two backward kernels mirror the forward traversal:
+[S, S] probability matrix: each tile recomputes p = exp(c q k^T - L)
+from the saved L, with c the softmax scale (``1 / sqrt(d)`` unless the
+configuration gives one).  Two backward kernels mirror the forward traversal:
 
   * dq  — grid (B*H, n_q, n_k), KV innermost; accumulates
-          dq += (p ∘ (do v^T - D)) k / sqrt(d) in VMEM scratch.
+          dq += c (p ∘ (do v^T - D)) k in VMEM scratch.
   * dkv — grid (B*H, n_k, n_q), Q innermost; accumulates per-QUERY-head
-          dk/dv tiles (dv += p^T do; dk += (p ∘ (do v^T - D))^T q / sqrt(d));
+          dk/dv tiles (dv += p^T do; dk += c (p ∘ (do v^T - D))^T q);
           GQA group-sum over the G query heads of each KV head happens
           outside the kernel so every output block is written exactly once
           (no output-revisiting hazards across the bh grid dim).
@@ -309,7 +310,7 @@ def _row_operands(positions, segment_ids, k_positions, k_segment_ids):
 
 
 def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
-              causal, block_q, block_k, interpret, save_lse):
+              causal, block_q, block_k, scale, interpret, save_lse):
     B, H, S, dh = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -324,7 +325,7 @@ def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
 
     fn = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, n_k=n_k, causal=causal, scale=1.0 / np.sqrt(dh),
+            _fwd_kernel, n_k=n_k, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, save_lse=save_lse,
             k_offset=Sk - S,
         ),
@@ -347,12 +348,11 @@ def _fwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
 
 
 def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
-              o, lse, do, causal, block_q, block_k, interpret):
+              o, lse, do, causal, block_q, block_k, scale, interpret):
     B, H, S, dh = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     G = H // Hkv
     n_q, n_k = S // block_q, Sk // block_k
-    scale = 1.0 / np.sqrt(dh)
     k_offset = Sk - S
     rows = _row_operands(positions, segment_ids, k_positions, k_segment_ids)
 
@@ -409,28 +409,30 @@ def _bwd_call(q, k, v, positions, segment_ids, k_positions, k_segment_ids,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
 def _packed_attention(q, k, v, positions, segment_ids, k_positions,
-                      k_segment_ids, causal, block_q, block_k, interpret):
+                      k_segment_ids, causal, block_q, block_k, scale,
+                      interpret):
     return _fwd_call(q, k, v, positions, segment_ids, k_positions,
-                     k_segment_ids, causal, block_q, block_k, interpret,
-                     save_lse=False)
+                     k_segment_ids, causal, block_q, block_k, scale,
+                     interpret, save_lse=False)
 
 
 def _packed_attention_fwd(q, k, v, positions, segment_ids, k_positions,
-                          k_segment_ids, causal, block_q, block_k, interpret):
+                          k_segment_ids, causal, block_q, block_k, scale,
+                          interpret):
     o, lse = _fwd_call(q, k, v, positions, segment_ids, k_positions,
-                       k_segment_ids, causal, block_q, block_k, interpret,
-                       save_lse=True)
+                       k_segment_ids, causal, block_q, block_k, scale,
+                       interpret, save_lse=True)
     return o, (q, k, v, positions, segment_ids, k_positions, k_segment_ids,
                o, lse)
 
 
-def _packed_attention_bwd(causal, block_q, block_k, interpret, res, do):
+def _packed_attention_bwd(causal, block_q, block_k, scale, interpret, res, do):
     q, k, v, positions, segment_ids, k_positions, k_segment_ids, o, lse = res
     dq, dk, dv = _bwd_call(q, k, v, positions, segment_ids, k_positions,
                            k_segment_ids, o, lse, do, causal, block_q,
-                           block_k, interpret)
+                           block_k, scale, interpret)
     dpos = np.zeros(positions.shape, jax.dtypes.float0)
     dseg = np.zeros(segment_ids.shape, jax.dtypes.float0)
     dkpos = np.zeros(k_positions.shape, jax.dtypes.float0)
@@ -454,12 +456,14 @@ def packed_attention_pallas(
     block_q: int = 128,
     block_k: Optional[int] = None,
     interpret: bool = False,
+    scale: float,
 ) -> jax.Array:
     """Packed flash attention; the k/v sequence may carry ``Sk - S`` extra
     leading rows (learned prefix-tuning k/v) with their own segment ids:
     ``k_segment_ids == -1`` marks a row visible to EVERY query of the batch
     row, any other negative value a row visible to none.  The kv tile
-    follows the q tile unless ``block_k`` is given (:func:`tiles`)."""
+    follows the q tile unless ``block_k`` is given (:func:`tiles`).  ``scale``
+    is the softmax scale."""
     B, S, H, dh = q.shape
     Sk = k.shape[1]
     block_q, block_k = tiles(S, Sk, block_q, block_k)
@@ -478,6 +482,7 @@ def packed_attention_pallas(
         q.transpose(head_major), k.transpose(head_major),
         v.transpose(head_major), positions.astype(jnp.int32),
         segment_ids.astype(jnp.int32), k_positions.astype(jnp.int32),
-        k_segment_ids.astype(jnp.int32), causal, block_q, block_k, interpret,
+        k_segment_ids.astype(jnp.int32), causal, block_q, block_k, scale,
+        interpret,
     )
     return o.transpose(head_major)

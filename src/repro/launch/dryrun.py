@@ -128,7 +128,7 @@ def parse_collectives(hlo: str) -> Dict[str, Any]:
 
 def _units(cfg: ArchConfig) -> int:
     if cfg.family == "hybrid":
-        return cfg.num_layers // cfg.hybrid_period
+        return cfg.n_periods
     if cfg.family == "ssm":
         return cfg.num_layers // cfg.slstm_period
     return cfg.num_layers
@@ -136,7 +136,7 @@ def _units(cfg: ArchConfig) -> int:
 
 def _with_units(cfg: ArchConfig, units: int, scan: bool, cost_blocks: Optional[int]) -> ArchConfig:
     if cfg.family == "hybrid":
-        L = units * cfg.hybrid_period
+        L = units * len(cfg.layer_pattern)
     elif cfg.family == "ssm":
         L = units * cfg.slstm_period
     else:

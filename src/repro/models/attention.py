@@ -59,6 +59,16 @@ def attention_spec(cfg: ArchConfig, cross: bool = False) -> Dict[str, ParamSpec]
 # ---------------------------------------------------------------------------
 
 
+def _prescale_q(q: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """``q`` rescaled so that a path whose softmax scale is fixed at
+    ``1 / sqrt(dh)`` (decode, striped context parallelism) applies the
+    configuration's scale."""
+    if not cfg.attention_scale:
+        return q
+    dh = q.shape[-1]
+    return (q.astype(jnp.float32) * (cfg.attention_scale * np.sqrt(dh))).astype(q.dtype)
+
+
 def _block_pairs(n_q: int, n_k: int, causal: bool, ratio: int) -> np.ndarray:
     """Static (i, j) block pair list; for causal, j*kb <= end of q block i."""
     pairs = []
@@ -114,13 +124,13 @@ def flash_attention_pairs(
     segment_ids: Optional[jax.Array] = None,  # [B, S]
     positions: Optional[jax.Array] = None,  # [B, S] (packed: within-segment)
     kv_prefix=None,  # (pk [B,P,Hkv,dh], pv [B,P,Hkv,dh], keep [B,P])
+    scale: float,  # softmax scale (``ArchConfig.softmax_scale``)
 ) -> jax.Array:
     B, S, H, dh = q.shape
     Hkv = k.shape[2]
     G = H // Hkv
     blk = _fit_block(S, block)
     n = S // blk
-    scale = 1.0 / np.sqrt(dh)
 
     qb = q.reshape(B, n, blk, Hkv, G, dh)
     kb = k.reshape(B, n, blk, Hkv, dh)
@@ -199,13 +209,13 @@ def flash_attention_kvscan(
     segment_ids: Optional[jax.Array] = None,
     positions: Optional[jax.Array] = None,  # [B, S]
     kv_prefix=None,  # (pk [B,P,Hkv,dh], pv [B,P,Hkv,dh], keep [B,P])
+    scale: float,  # softmax scale (``ArchConfig.softmax_scale``)
 ) -> jax.Array:
     B, S, H, dh = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
     blk = _fit_block(Sk, kv_block)
     n = Sk // blk
-    scale = 1.0 / np.sqrt(dh)
 
     q5 = q.reshape(B, S, Hkv, G, dh)
     kb = k.reshape(B, n, blk, Hkv, dh)
@@ -298,7 +308,7 @@ def _project_qkv(p, x, cfg: ArchConfig, positions, mrope_positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if cfg.attention != "none" and positions is not None:
+    if cfg.attention != "none" and cfg.positional == "rope" and positions is not None:
         if cfg.mrope and mrope_positions is not None:
             q = apply_mrope(q, mrope_positions, cfg.rope_theta, cfg.mrope_sections)
             k = apply_mrope(k, mrope_positions, cfg.rope_theta, cfg.mrope_sections)
@@ -331,7 +341,8 @@ def attention_apply(
     if kv_override is not None:
         k, v = kv_override
         # Cross-attention: q/kv lengths differ -> kvscan handles ragged Sk.
-        out = flash_attention_kvscan(q, k, v, kv_block=cfg.attn_kv_block, causal=False)
+        out = flash_attention_kvscan(q, k, v, kv_block=cfg.attn_kv_block, causal=False,
+                                     scale=cfg.softmax_scale())
         from repro.peft.hooks import apply_base_op
         y = apply_base_op("attn_o", out, p["w_o"], "bshk,hkd->bsd", bias=p.get("b_o"))
         return (y, (k, v)) if return_kv else y
@@ -363,7 +374,7 @@ def attention_apply(
         P_sz = mesh.shape["model"] if (mesh and "model" in mesh.axis_names) else 1
         blk = max(min(cfg.attn_q_block, 256, S // (4 * P_sz)), 16)
         out = striped_cp_attention(
-            q, k, v, positions, segment_ids, mesh, axis="model", block=blk,
+            _prescale_q(q, cfg), k, v, positions, segment_ids, mesh, axis="model", block=blk,
             kv_prefix=prefix,
         )
         out = shard(out, "batch", "seq", None, None)
@@ -382,6 +393,7 @@ def attention_apply(
             segment_ids=segment_ids, positions=positions if causal else None,
             prefix_kv=prefix[:2] if prefix is not None else None,
             prefix_keep=prefix[2] if prefix is not None else None,
+            scale=cfg.softmax_scale(),
         )
         out = shard(out, "batch", None, "heads", None)
     else:  # kvscan (CP): q stays seq-sharded, kv gathered
@@ -391,7 +403,7 @@ def attention_apply(
         out = flash_attention_kvscan(
             q, k, v, kv_block=cfg.attn_kv_block, causal=causal,
             segment_ids=segment_ids, positions=positions if causal else None,
-            kv_prefix=prefix,
+            kv_prefix=prefix, scale=cfg.softmax_scale(),
         )
         out = shard(out, "batch", "seq", None, None)
     from repro.peft.hooks import apply_base_op
@@ -448,7 +460,8 @@ def attention_decode_apply(
         new_len = pos + 1
     else:  # cross-attention: cache fixed
         k_cache, v_cache, new_len = cache["k"], cache["v"], pos
-    out = decode_attention(q, k_cache, v_cache, new_len, cache_start=lo)
+    out = decode_attention(_prescale_q(q, cfg), k_cache, v_cache, new_len,
+                           cache_start=lo)
     from repro.peft.hooks import apply_base_op
 
     y = apply_base_op("attn_o", out, p["w_o"], "bshk,hkd->bsd", bias=p.get("b_o"))

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ArchConfig
+from repro.configs import CONV_W, ArchConfig
 from repro.distributed.sharding import shard
 from repro.models.layers import ParamSpec, rms_norm
 from repro.peft.hooks import apply_base_op
@@ -148,9 +148,6 @@ def gla_decode_step(
 # Mamba2 block
 # ---------------------------------------------------------------------------
 
-CONV_W = 4
-
-
 def mamba2_dims(cfg: ArchConfig) -> Tuple[int, int, int]:
     d_in = cfg.ssm_expand * cfg.d_model
     n_heads = d_in // cfg.ssm_head_dim
@@ -161,7 +158,7 @@ def mamba2_spec(cfg: ArchConfig) -> Dict[str, ParamSpec]:
     d = cfg.d_model
     d_in, nh, st = mamba2_dims(cfg)
     # in-proj: [z (d_in), x (d_in), B (st), C (st), dt (nh)]
-    return {
+    s = {
         "w_in": ParamSpec((d, 2 * d_in + 2 * st + nh), ("embed", "ssm_inner")),
         "conv": ParamSpec((CONV_W, d_in + 2 * st), (None, "ssm_inner"), scale=0.1),
         "dt_bias": ParamSpec((nh,), (None,), init="zeros"),
@@ -170,13 +167,17 @@ def mamba2_spec(cfg: ArchConfig) -> Dict[str, ParamSpec]:
         "norm": ParamSpec((d_in,), ("ssm_inner",), init="ones"),
         "w_out": ParamSpec((d_in, d), ("ssm_inner", "embed")),
     }
+    if cfg.ssm_conv_bias:
+        s["conv_b"] = ParamSpec((d_in + 2 * st,), ("ssm_inner",), init="zeros")
+    return s
 
 
-def _causal_depthwise_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """x: [B, S, C]; w: [W, C] — causal depthwise conv."""
+def _causal_depthwise_conv(x: jax.Array, w: jax.Array,
+                           b: Optional[jax.Array] = None) -> jax.Array:
+    """x: [B, S, C]; w: [W, C]; b: [C] or None — causal depthwise conv."""
     W = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
-    out = jnp.zeros_like(x)
+    out = jnp.zeros_like(x) if b is None else jnp.broadcast_to(b, x.shape).astype(x.dtype)
     for i in range(W):  # W is tiny (4): unrolled taps
         out = out + xp[:, i : i + x.shape[1], :] * w[i]
     return out
@@ -199,11 +200,13 @@ def mamba2_apply(
     )
     conv_in = jnp.concatenate([xin, bmat, cmat], axis=-1)
     if state is None:
-        conv_out = _causal_depthwise_conv(conv_in, p["conv"])
+        conv_out = _causal_depthwise_conv(conv_in, p["conv"], p.get("conv_b"))
     else:
         # decode: roll the conv window buffer [B, CONV_W-1, C]
         buf = jnp.concatenate([state["conv"], conv_in], axis=1)
         conv_out = (buf[:, -CONV_W:, :] * p["conv"][None]).sum(axis=1, keepdims=True)
+        if "conv_b" in p:
+            conv_out = conv_out + p["conv_b"]
         state = dict(state, conv=buf[:, 1:, :])
     conv_out = jax.nn.silu(conv_out.astype(jnp.float32)).astype(x.dtype)
     xin, bmat, cmat = jnp.split(conv_out, [d_in, d_in + st], axis=-1)

@@ -1,9 +1,11 @@
 """Unified backbone assembly for all assigned architecture families.
 
 One ``Model`` facade supports: dense/VLM llama-style GQA, fine-grained MoE,
-Zamba2-style hybrid (Mamba2 + weight-shared attention block), xLSTM
-(mLSTM/sLSTM super-blocks), and Whisper-style encoder-decoder (stub conv
-frontend — precomputed frame embeddings in).
+hybrids that repeat a period of Mamba-2 and attention layers (Zamba2: MLP-less
+Mamba-2 layers and one weight-shared attention+MLP block; Granite-4.0-H: every
+layer a mixer plus its own MLP), xLSTM (mLSTM/sLSTM super-blocks), and
+Whisper-style encoder-decoder (stub conv frontend — precomputed frame
+embeddings in).
 
 Layers are stored stacked ``[L, ...]`` and executed with ``lax.scan`` (+
 optional ``jax.checkpoint`` remat) for compact HLO and O(1) per-layer
@@ -94,6 +96,12 @@ def _scan_or_loop(body, carry, xs, length: int, use_scan: bool):
     return carry, stacked
 
 
+def _times(a: jax.Array, m: float) -> jax.Array:
+    """``a * m`` computed in float32: the multiplier rounded to a bfloat16
+    ``a``'s type would scale by another number (0.22 becomes 0.2197)."""
+    return a if m == 1.0 else (a.astype(jnp.float32) * m).astype(a.dtype)
+
+
 class Model:
     """Backbone facade.  ``attn_mode`` in {"pairs", "kvscan"} (DESIGN.md §5)."""
 
@@ -134,15 +142,20 @@ class Model:
         if cfg.family in ("dense", "vlm", "moe"):
             spec["layers"] = _stack_specs(self._layer_spec(), cfg.num_layers)
         elif cfg.family == "hybrid":
-            n_super = cfg.num_layers // cfg.hybrid_period
-            per = cfg.hybrid_period - 1
+            # per kind, [period, index within the period, ...]
+            n_per, pat = cfg.n_periods, cfg.layer_pattern
             mamba_layer = {"ln": _norm_spec(cfg.d_model, False), "mamba": ssm.mamba2_spec(cfg)}
-            spec["blocks"] = {"mamba": _stack_specs(_stack_specs(mamba_layer, per), n_super)}
-            shared = self._layer_spec()
+            if cfg.mamba_mlp:
+                mamba_layer["ln2"] = _norm_spec(cfg.d_model, False)
+                mamba_layer["mlp"] = mlp_spec(cfg.d_model, cfg.d_ff, cfg.gated_mlp)
+            spec["blocks"] = {"mamba": _stack_specs(
+                _stack_specs(mamba_layer, pat.count("mamba")), n_per)}
+            attn_layer = self._layer_spec()
             if cfg.shared_attention:
-                spec["shared_attn"] = shared  # one copy, reused per super-block
+                spec["shared_attn"] = attn_layer  # one copy, reused every period
             else:
-                spec["blocks"]["attn"] = _stack_specs(shared, n_super)
+                spec["blocks"]["attn"] = _stack_specs(
+                    _stack_specs(attn_layer, pat.count("attention")), n_per)
         elif cfg.family == "ssm":
             n_super = cfg.num_layers // cfg.slstm_period
             per = cfg.slstm_period - 1
@@ -200,7 +213,7 @@ class Model:
         if collect_kv:
             a, kv = a
             aux_sink["__kv__"] = kv
-        x = shard(x + a, "batch", "seq", None)
+        x = shard(x + self._res(a), "batch", "seq", None)
         h = _apply_norm(lp["ln2"], x, cfg.norm_eps)
         if cfg.family == "moe" and "moe" in lp:
             y, aux = moe_apply(lp["moe"], h, cfg)
@@ -210,7 +223,48 @@ class Model:
                 aux_sink[k] = aux_sink.get(k, 0.0) + v
         else:
             y = mlp_apply(lp["mlp"], h, cfg.gated_mlp)
-        return shard(x + y, "batch", "seq", None)
+        return shard(x + self._res(y), "batch", "seq", None)
+
+    def _res(self, y):
+        """A residual branch under ``residual_multiplier``."""
+        return _times(y, self.cfg.residual_multiplier)
+
+    def _embed(self, params, tokens):
+        x = embed_apply(params["embed"], tokens)
+        return _times(x, self.cfg.embedding_multiplier)
+
+    def _mamba_layer(self, lp, x, *, reset=None, state=None):
+        """One Mamba-2 layer: the mixer (spanned ``mixer.mamba``, in- through
+        out-projection), then its own MLP where the configuration has one.
+        ``state`` is the decode state ({"h", "conv"}) or None."""
+        cfg = self.cfg
+        h = _apply_norm(lp["ln"], x, cfg.norm_eps)
+        with jax.named_scope("mixer.mamba"):
+            y, state = ssm.mamba2_apply(lp["mamba"], h, cfg, state=state, reset=reset)
+        x = shard(x + self._res(y), "batch", "seq", None)
+        if "mlp" in lp:
+            h = _apply_norm(lp["ln2"], x, cfg.norm_eps)
+            y = mlp_apply(lp["mlp"], h, cfg.gated_mlp)
+            x = shard(x + self._res(y), "batch", "seq", None)
+        return x, state
+
+    def _period(self):
+        """The layers of one hybrid period, in order: (mixer, parameter group,
+        index within the group's period stack; None for the shared block)."""
+        cfg = self.cfg
+        seen: Dict[str, int] = {}
+        out = []
+        for mixer in cfg.layer_pattern:
+            if mixer == "mamba":
+                group = "mamba"
+            elif mixer == "attention":
+                group = "shared_attn" if cfg.shared_attention else "attn"
+            else:
+                raise ValueError(f"layer kind {mixer!r}")
+            i = seen.get(group, 0)
+            seen[group] = i + 1
+            out.append((mixer, group, None if group == "shared_attn" else i))
+        return out
 
     # ------------------------------------------------------------------
     # Forward (training / prefill)
@@ -236,7 +290,7 @@ class Model:
         mrope_positions = batch.get("mrope_positions") if cfg.mrope else None
         reset = batch.get("reset")  # SSM segment-boundary resets
 
-        x = embed_apply(params["embed"], tokens)
+        x = self._embed(params, tokens)
         x = shard(x, "batch", "seq", None)
         aux: Dict[str, jax.Array] = {}
 
@@ -269,6 +323,8 @@ class Model:
 
     def _logits(self, params, x):
         logits = unembed_apply(params["embed"], x)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
         if self.vocab_padded != self.cfg.vocab_size:
             pad_mask = jnp.arange(self.vocab_padded) >= self.cfg.vocab_size
             logits = jnp.where(pad_mask, -1e9, logits.astype(jnp.float32)).astype(logits.dtype)
@@ -324,51 +380,51 @@ class Model:
                 aux["__kv__"] = jax.tree.map(lambda *a: jnp.stack(a), *kvs)
         return x, aux
 
-    # ---- hybrid (zamba2) ----
+    # ---- hybrid: a period of layer kinds, repeated ----
 
     def _run_hybrid(self, params, x, adapters, ctx_factory, *, positions, segment_ids, reset):
+        """Scan over the periods; each layer of a period is rematerialised on
+        its own, so the backward holds one layer's internals at a time.  A
+        layer takes the period's whole slice of the stacks and picks its own
+        inside the checkpoint, so the scan's residuals are its inputs."""
         cfg = self.cfg
-        blocks = params["blocks"]
-        shared = params.get("shared_attn")
-        ad_mamba = adapters.get("mamba") if isinstance(adapters, dict) else None
-        ad_shared = adapters.get("shared_attn") if isinstance(adapters, dict) else None
-        per = cfg.hybrid_period - 1
-        aux: Dict[str, jax.Array] = {}
+        ad = adapters if isinstance(adapters, dict) else {}
+        shared, ad_shared = params.get("shared_attn"), ad.get("shared_attn")
+        ad_blocks = {k: v for k, v in ad.items() if k != "shared_attn"}
 
-        def super_block(x, mb, ad):
-            for i in range(per):
-                lp = _slice_layer(mb, i)
-                adi = _slice_layer(ad, i) if ad is not None else None
-                with adapter_scope(ctx_factory(adi) if ctx_factory and adi is not None else None):
-                    h = _apply_norm(lp["ln"], x, cfg.norm_eps)
-                    y, _ = ssm.mamba2_apply(lp["mamba"], h, cfg, reset=reset)
-                x = shard(x + y, "batch", "seq", None)
-            sink: Dict[str, jax.Array] = {}
-            with adapter_scope(ctx_factory(ad_shared) if ctx_factory and ad_shared is not None else None):
-                x = self._attn_mlp_block(
-                    shared, x, causal=True, positions=positions,
-                    mrope_positions=None, segment_ids=segment_ids, aux_sink=sink,
-                )
-            return x, sink
+        def layer_fn(mixer, group, i):
+            def f(x, blocks, ad_blocks, shared, ad_shared):
+                if i is None:
+                    lp, a = shared, ad_shared
+                else:
+                    lp = _slice_layer(blocks[group], i)
+                    a = _slice_layer(ad_blocks[group], i) if group in ad_blocks else None
+                with adapter_scope(ctx_factory(a) if ctx_factory and a is not None else None):
+                    if mixer == "mamba":
+                        x, _ = self._mamba_layer(lp, x, reset=reset)
+                    else:
+                        x = self._attn_mlp_block(
+                            lp, x, causal=True, positions=positions,
+                            mrope_positions=None, segment_ids=segment_ids, aux_sink={},
+                        )
+                return x
+            return jax.checkpoint(f) if cfg.remat else f
 
-        n_super = cfg.num_layers // cfg.hybrid_period
+        layers = [layer_fn(*spec) for spec in self._period()]
+
+        def period(x, blocks, ad_blocks):
+            for f in layers:
+                x = f(x, blocks, ad_blocks, shared, ad_shared)
+            return x
+
         if cfg.scan_layers:
-            def scan_body(x, xs):
-                mb, ad = xs
-                fn = jax.checkpoint(super_block) if cfg.remat else super_block
-                return fn(x, mb, ad)
-
-            x, sinks = jax.lax.scan(scan_body, x, (blocks["mamba"], ad_mamba))
-            aux = {k: v.sum() for k, v in sinks.items()} if sinks else {}
+            x, _ = jax.lax.scan(lambda x, xs: (period(x, *xs), None), x,
+                                (params["blocks"], ad_blocks))
         else:
-            for i in range(n_super):
-                x, sink = super_block(
-                    x, _slice_layer(blocks["mamba"], i),
-                    _slice_layer(ad_mamba, i) if ad_mamba is not None else None,
-                )
-                for k, v in sink.items():
-                    aux[k] = aux.get(k, 0.0) + v
-        return x, aux
+            for p in range(cfg.n_periods):
+                x = period(x, _slice_layer(params["blocks"], p),
+                           _slice_layer(ad_blocks, p))
+        return x, {}
 
     # ---- xlstm ----
 
@@ -514,13 +570,15 @@ class Model:
         if cfg.family in ("dense", "vlm", "moe"):
             state["kv"] = kv(cfg.num_layers)
         elif cfg.family == "hybrid":
-            n_super = cfg.num_layers // cfg.hybrid_period
-            per = cfg.hybrid_period - 1
+            # per kind, [period, index within the period, ...]
+            n_per, pat = cfg.n_periods, cfg.layer_pattern
             ms = ssm.mamba2_init_state(cfg, batch)
             state["mamba"] = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (n_super, per) + a.shape), ms
+                lambda a: jnp.broadcast_to(a, (n_per, pat.count("mamba")) + a.shape), ms
             )
-            state["kv"] = kv(n_super)
+            n_attn = pat.count("attention")
+            state["kv"] = jax.tree.map(lambda a: a.reshape((n_per, n_attn) + a.shape[1:]),
+                                       kv(n_per * n_attn))
         elif cfg.family == "ssm":
             n_super = cfg.num_layers // cfg.slstm_period
             per = cfg.slstm_period - 1
@@ -598,7 +656,7 @@ class Model:
         cfg = self.cfg
         pos = state["pos"]  # [] or [B]: real-token count (RoPE position)
         lo = state.get("lo")  # [B] per-row cache-window start, or None
-        x = embed_apply(params["embed"], tokens)  # [B, 1, d]
+        x = self._embed(params, tokens)  # [B, 1, d]
         if cfg.family == "audio":
             max_len = state["kv"]["k"].shape[2]
             pe = sinusoidal_positions(max_len, cfg.d_model)  # static table
@@ -644,37 +702,40 @@ class Model:
             new_state["kv"] = {"k": ks, "v": vs}
 
         elif cfg.family == "hybrid":
-            per = cfg.hybrid_period - 1
-            ad_mamba = adapters.get("mamba") if isinstance(adapters, dict) else None
-            ad_shared = adapters.get("shared_attn") if isinstance(adapters, dict) else None
+            ad = adapters if isinstance(adapters, dict) else {}
+            shared, ad_shared = params.get("shared_attn"), ad.get("shared_attn")
 
-            def super_body(x, xs):
-                mb, mstate, kc, vc, ad = xs
-                mstates_new = []
-                for i in range(per):
-                    lp = _slice_layer(mb, i)
-                    st = _slice_layer(mstate, i)
-                    adi = _slice_layer(ad, i) if ad is not None else None
-                    with adapter_scope(ctx_factory(adi) if ctx_factory and adi is not None else None):
-                        h = _apply_norm(lp["ln"], x, cfg.norm_eps)
-                        y, st2 = ssm.mamba2_apply(lp["mamba"], h, cfg, state=st)
-                    mstates_new.append(st2)
-                    x = x + y
-                shared = params["shared_attn"]
-                with adapter_scope(ctx_factory(ad_shared) if ctx_factory and ad_shared is not None else None):
-                    h = _apply_norm(shared["ln1"], x, cfg.norm_eps)
-                    a, cache = attn.attention_decode_apply(
-                        shared["attn"], h, cfg, attn_cache(kc, vc))
-                    x = x + a
-                    h = _apply_norm(shared["ln2"], x, cfg.norm_eps)
-                    x = x + mlp_apply(shared["mlp"], h, cfg.gated_mlp)
-                mst = jax.tree.map(lambda *a: jnp.stack(a), *mstates_new)
-                return x, (mst, cache["k"], cache["v"])
+            def period_body(x, xs):
+                blocks, ad_blocks, mstate, kc, vc = xs
+                mst, ks, vs = [], [], []
+                for mixer, group, i in self._period():
+                    if i is None:
+                        lp, a = shared, ad_shared
+                    else:
+                        lp = _slice_layer(blocks[group], i)
+                        a = _slice_layer(ad_blocks[group], i) if group in ad_blocks else None
+                    with adapter_scope(ctx_factory(a) if ctx_factory and a is not None else None):
+                        if mixer == "mamba":
+                            x, st = self._mamba_layer(lp, x, state=_slice_layer(mstate, i))
+                            mst.append(st)
+                            continue
+                        j = len(ks)
+                        h = _apply_norm(lp["ln1"], x, cfg.norm_eps)
+                        y, cache = attn.attention_decode_apply(
+                            lp["attn"], h, cfg, attn_cache(kc[j], vc[j]))
+                        x = x + self._res(y)
+                        h = _apply_norm(lp["ln2"], x, cfg.norm_eps)
+                        x = x + self._res(mlp_apply(lp["mlp"], h, cfg.gated_mlp))
+                    ks.append(cache["k"])
+                    vs.append(cache["v"])
+                stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs)  # noqa: E731
+                return x, (stack(mst), stack(ks), stack(vs))
 
-            xs = (params["blocks"]["mamba"], state["mamba"],
-                  state["kv"]["k"], state["kv"]["v"], ad_mamba)
-            n_super = cfg.num_layers // cfg.hybrid_period
-            x, (mst, ks, vs) = _scan_or_loop(super_body, x, xs, n_super, cfg.scan_layers)
+            ad_blocks = {k: v for k, v in ad.items() if k != "shared_attn"}
+            xs = (params["blocks"], ad_blocks, state["mamba"],
+                  state["kv"]["k"], state["kv"]["v"])
+            x, (mst, ks, vs) = _scan_or_loop(period_body, x, xs, cfg.n_periods,
+                                             cfg.scan_layers)
             new_state["mamba"] = mst
             new_state["kv"] = {"k": ks, "v": vs}
 

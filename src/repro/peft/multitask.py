@@ -186,15 +186,22 @@ class MultiTaskAdapters:
         if cfg.family in ("dense", "vlm", "moe", "audio"):
             return self._stack(per, cfg.num_layers)
         if cfg.family == "hybrid":
-            n_super = cfg.num_layers // cfg.hybrid_period
+            # the model's layout: per kind [period, index within it, ...]
+            # (the weight-shared attention block unstacked); MLP sites sit
+            # on every layer that has an MLP
+            n_per, pat = cfg.n_periods, cfg.layer_pattern
             ssm_targets = {"ssm_in", "ssm_out"}
             attn_targets = set(self.dims) - ssm_targets
-            return {
-                "mamba": self._stack(
-                    self._per_layer_spec(ssm_targets), n_super, cfg.hybrid_period - 1
-                ),
-                "shared_attn": self._per_layer_spec(attn_targets),
-            }
+            mlp_targets = {n for n in attn_targets if n.startswith("mlp_")}
+            mamba_targets = ssm_targets | (mlp_targets if cfg.mamba_mlp else set())
+            out = {"mamba": self._stack(self._per_layer_spec(mamba_targets),
+                                        n_per, pat.count("mamba"))}
+            attn = self._per_layer_spec(attn_targets)
+            if cfg.shared_attention:
+                out["shared_attn"] = attn
+            else:
+                out["attn"] = self._stack(attn, n_per, pat.count("attention"))
+            return out
         if cfg.family == "ssm":
             n_super = cfg.num_layers // cfg.slstm_period
             return {

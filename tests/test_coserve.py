@@ -147,7 +147,7 @@ def test_init_kv_cache_prefix_layout_matches_train_path(key):
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     q, k, v = attn_mod._project_qkv(p, x, cfg, pos, None)
     ref = flash_attention_pairs(q, k, v, block=4, causal=True, positions=pos,
-                                kv_prefix=(pk, pv, keep))
+                                kv_prefix=(pk, pv, keep), scale=cfg.softmax_scale())
     ref = jnp.einsum("bshk,hkd->bsd", ref, p["w_o"])
     # decode path: per-row prefix-aware cache from the reference constructor
     cache = init_kv_cache(cfg, B, S, dtype=jnp.float32, prefix_reserve=pres,
@@ -180,7 +180,8 @@ def test_striped_cp_prefix_broadcast(key):
     keep = jnp.asarray([[1.0] * P, [0.0] * P])  # row 1 owns no prefix
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     ref = flash_attention_pairs(q, k, v, block=32, causal=True,
-                                positions=pos, kv_prefix=(pk, pv, keep))
+                                positions=pos, kv_prefix=(pk, pv, keep),
+                                scale=1 / np.sqrt(dh))
     out = striped_cp_attention(q, k, v, pos, None, None, block=32,
                                kv_prefix=(pk, pv, keep))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

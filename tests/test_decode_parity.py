@@ -76,7 +76,7 @@ def test_rules_matrix_cells_valid():
                 for a in tgt:
                     assert a in ("pod", "data", "model"), (arch, shp, k, v)
             seen += 1
-    assert seen == 32  # 8 archs x 3 + 2 archs x 4
+    assert seen == 36  # 8 archs x 3 + 3 archs x 4
 
 
 def test_cluster_simulator_policies():

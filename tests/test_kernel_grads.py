@@ -209,7 +209,8 @@ def test_packed_attention_grads_match_ref(dtype, B, S, H, Hkv, dh, bq, bk,
 
     def loss_pal(q, k, v):
         o = packed_attention_pallas(q, k, v, seg, pos, causal, block_q=bq,
-                                    block_k=bk, interpret=INTERPRET)
+                                    block_k=bk, interpret=INTERPRET,
+                                    scale=1 / np.sqrt(dh))
         return (o.astype(jnp.float32) * g.astype(jnp.float32)).sum()
 
     def loss_ref(q, k, v):
@@ -244,7 +245,8 @@ def test_packed_attention_multisegment_grads(key):
 
     def loss_pal(q, k, v):
         o = packed_attention_pallas(q, k, v, seg, pos, True, block_q=32,
-                                    block_k=32, interpret=INTERPRET)
+                                    block_k=32, interpret=INTERPRET,
+                                    scale=1 / np.sqrt(dh))
         return (o * g).sum()
 
     def loss_ref(q, k, v):
@@ -313,7 +315,8 @@ def _check_prefix_rows(key, *, B, S, H, Hkv, dh, P, block_q):
                 o = kops.packed_attention(q, k, v, segment_ids=seg,
                                           positions=pos, causal=True,
                                           prefix_kv=(pk, pv),
-                                          prefix_keep=keep, block_q=block_q)
+                                          prefix_keep=keep, block_q=block_q,
+                                          scale=1 / np.sqrt(dh))
                 return (o * g).sum()
 
             gp = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, pk, pv)

@@ -85,7 +85,7 @@ def test_packed_attention_kernel(dtype, B, S, H, Hkv, dh, bq, bk, causal, packed
         assert tiles(S, S, bq) == (bq, bq) and bq != 128
     ref = packed_attention_ref(q, k, v, seg, pos, causal)
     out = packed_attention_pallas(q, k, v, seg, pos, causal, block_q=bq, block_k=bk,
-                                  interpret=True)
+                                  interpret=True, scale=1 / np.sqrt(dh))
     tol = 4e-2 if dtype == jnp.bfloat16 else 3e-4
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=tol, atol=tol
@@ -124,8 +124,8 @@ def test_flash_pairs_matches_dense_ref(key):
     k = jax.random.normal(ks[1], (B, S, Hkv, dh), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, Hkv, dh), jnp.float32)
     ref = packed_attention_ref(q, k, v, None, None, True)
-    out1 = flash_attention_pairs(q, k, v, block=32, causal=True)
-    out2 = flash_attention_kvscan(q, k, v, kv_block=32, causal=True)
+    out1 = flash_attention_pairs(q, k, v, block=32, causal=True, scale=1 / np.sqrt(dh))
+    out2 = flash_attention_kvscan(q, k, v, kv_block=32, causal=True, scale=1 / np.sqrt(dh))
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), rtol=2e-4, atol=2e-4)
 

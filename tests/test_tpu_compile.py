@@ -9,6 +9,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and each test worker imports every
 test file.
 """
+import functools
 import re
 
 import jax
@@ -29,6 +30,9 @@ from repro.launch import compile_cache
 SMOLLM = dict(B=2, S=2048, H=15, Hkv=5, dh=64)
 # zamba2-2.7b's mamba mixer: 80 heads, dk = dv = 64
 ZAMBA2 = dict(B=1, S=2048, H=80, d=64)
+# granite-4.0-h-micro's training cell: 2 rows of 4096; Mamba-2 64 heads,
+# dk = d_state 128, dv = head 64, chunk 256; attention 32 / 8 heads of 64
+GRANITE = dict(B=2, S=4096, H=64, dk=128, dv=64, chunk=256, Hq=32, Hkv=8, dh=64)
 
 
 @pytest.fixture(scope="module")
@@ -107,8 +111,9 @@ def test_packed_attention_compiles(one_chip):
     B, S, H, Hkv, dh = (SMOLLM[k] for k in ("B", "S", "H", "Hkv", "dh"))
     qkv = (_spec(one_chip, (B, S, H, dh)), _spec(one_chip, (B, S, Hkv, dh)),
            _spec(one_chip, (B, S, Hkv, dh)))
-    _compile_for_chip(packed_attention_pallas, *qkv)
-    _compile_for_chip(_grads(packed_attention_pallas, (0, 1, 2)), *qkv,
+    attn = functools.partial(packed_attention_pallas, scale=dh ** -0.5)
+    _compile_for_chip(attn, *qkv)
+    _compile_for_chip(_grads(attn, (0, 1, 2)), *qkv,
                       names=("packed_attention_fwd", "packed_attention_dq",
                              "packed_attention_dkv"))
 
@@ -119,7 +124,7 @@ def test_packed_attention_prefix_rows_compile(one_chip, pallas_tier):
 
     def attn(q, k, v, pk, pv, keep):
         return _grads(lambda q, k, v, pk, pv: kops.packed_attention(
-            q, k, v, prefix_kv=(pk, pv), prefix_keep=keep),
+            q, k, v, prefix_kv=(pk, pv), prefix_keep=keep, scale=dh ** -0.5),
             (0, 1, 2, 3, 4))(q, k, v, pk, pv)
 
     _compile_for_chip(
@@ -146,12 +151,14 @@ def test_packed_attention_train_tiles_compile(one_chip, pallas_tier, B, S, H,
            _spec(one_chip, (B, S, Hkv, dh)))
     if not P:
         _compile_for_chip(_grads(lambda q, k, v: kops.packed_attention(
-            q, k, v, block_q=block_q), (0, 1, 2)), *qkv, names=names)
+            q, k, v, block_q=block_q, scale=dh ** -0.5), (0, 1, 2)), *qkv,
+            names=names)
         return
 
     def attn(q, k, v, pk, pv, keep):
         return _grads(lambda q, k, v, pk, pv: kops.packed_attention(
-            q, k, v, prefix_kv=(pk, pv), prefix_keep=keep, block_q=block_q),
+            q, k, v, prefix_kv=(pk, pv), prefix_keep=keep, block_q=block_q,
+            scale=dh ** -0.5),
             (0, 1, 2, 3, 4))(q, k, v, pk, pv)
 
     _compile_for_chip(attn, *qkv, _spec(one_chip, (B, P, Hkv, dh)),
@@ -185,6 +192,52 @@ def test_mamba_scan_compiles(one_chip, with_reset):
 
     _compile_for_chip(fwd_bwd, *args, names=(
         "mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"))
+
+
+def test_granite_mamba_scan_compiles(one_chip):
+    """Forward and both backward kernels at granite's widths: the state
+    tile is (128, 64) f32, the chunk (256, 128) / (256, 64)."""
+    g = GRANITE
+    B, S, H = g["B"], g["S"], g["H"]
+    args = [_spec(one_chip, (B, S, H, g["dk"]))] * 2 + [
+        _spec(one_chip, (B, S, H, g["dv"]))] + [
+        _spec(one_chip, (B, S, H), jnp.float32)] * 2
+
+    def fwd_bwd(q, k, v, la, li):
+        return _grads(lambda *a: mamba_scan_pallas(*a, chunk=g["chunk"]),
+                      (0, 1, 2, 3, 4))(q, k, v, la, li)
+
+    _compile_for_chip(fwd_bwd, *args, names=(
+        "mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"))
+
+
+def test_granite_attention_compiles_with_its_scale(one_chip, pallas_tier):
+    g = GRANITE
+    B, S = g["B"], g["S"]
+    qkv = (_spec(one_chip, (B, S, g["Hq"], g["dh"])),
+           _spec(one_chip, (B, S, g["Hkv"], g["dh"])),
+           _spec(one_chip, (B, S, g["Hkv"], g["dh"])))
+    _compile_for_chip(_grads(lambda q, k, v: kops.packed_attention(
+        q, k, v, block_q=512, scale=1.0 / 64), (0, 1, 2)), *qkv,
+        names=("packed_attention_fwd", "packed_attention_dq",
+               "packed_attention_dkv"))
+
+
+@pytest.mark.parametrize("d_in,d_out", [(2048, 8512), (4096, 2048)])
+def test_granite_grouped_lora_compiles(one_chip, d_in, d_out):
+    """Rank 16 on ``ssm_in`` (d_out 8512, not a multiple of 128: the kernel
+    takes d_out whole) and ``ssm_out``, over the cell's 8192 tokens."""
+    M, r, T = GRANITE["B"] * GRANITE["S"], 16, 1
+
+    def fwd_bwd(x, a, b, rows, scale):
+        return _grads(lambda x, a, b, s: grouped_lora_pallas(x, a, b, rows, s),
+                      (0, 1, 2, 3))(x, a, b, scale)
+
+    _compile_for_chip(
+        fwd_bwd, _spec(one_chip, (M, d_in)), _spec(one_chip, (T, d_in, r)),
+        _spec(one_chip, (T, r, d_out)), _spec(one_chip, (M,), jnp.int32),
+        _spec(one_chip, (T,), jnp.float32),
+        names=("grouped_lora_fwd", "grouped_lora_bwd"))
 
 
 @pytest.mark.parametrize("mkn", [(4096, 960, 2560), (4096, 2560, 10240),
@@ -239,6 +292,47 @@ def test_train_step_names_its_kernels_and_phases(one_chip, pallas_tier):
     assert any("/jvp(train.forward)/" in s for s in scopes)
     assert any("/transpose(jvp(train.forward))/" in s for s in scopes)
     assert any("/train.optimizer/" in s for s in scopes)
+
+
+def test_granite_step_spans_its_mamba_mixers(one_chip, pallas_tier):
+    """granite-4.0-h-micro's training step at full width (one Mamba and one
+    attention layer): every kernel it runs is named, and the scan kernels,
+    forward and backward, carry the ``mixer.mamba`` scope in the op_names
+    that the device-trace readers join to them."""
+    from repro.configs import get_config
+    from repro.core import (ExecutionPlanner, ModelGenerator, ParallelismSpec,
+                            PEFTEngine)
+    from repro.data import HTaskLoader, make_task
+    from repro.peft.adapters import LORA
+    from repro.peft.methods import AdapterConfig
+
+    cfg = get_config("granite-4.0-h-micro").with_overrides(
+        num_layers=2, layer_pattern=("mamba", "attention"), vocab_size=1024)
+    tasks = [make_task("t0", "sst2", 2, AdapterConfig(
+        LORA, rank=16, targets=("ssm_in", "ssm_out", "attn_q", "attn_k",
+                                "attn_v", "attn_o")), seed=0)]
+    plan = ExecutionPlanner(cfg, ParallelismSpec()).plan(tasks, n_micro=1)
+    gen = ModelGenerator(cfg)
+    gen.register_tasks(tasks)
+    eng = PEFTEngine(gen, plan, lr=1e-3)
+    batch = next(HTaskLoader(tasks, plan.alignment[0], cfg.vocab_size))
+    n_acc = max(len(plan.tasks), sum(eng.reg.mta.kind_capacity.values()))
+    args = (eng.backbone, eng.reg.adapter_params, eng.reg.opt_state,
+            eng._slot_steps, batch, eng._member_ids[0],
+            (jnp.float32(0), jnp.zeros((n_acc,), jnp.float32)))
+    specs = jax.tree.map(lambda a: _spec(one_chip, jnp.shape(a), jnp.result_type(a)),
+                         args)
+    text = eng._make_step(0).lower(*specs).compile().as_text()
+    scan = {"mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"}
+    want = scan | {"packed_attention_fwd", "packed_attention_dq",
+                   "packed_attention_dkv", "grouped_lora_fwd", "grouped_lora_bwd"}
+    found = set(re.findall(r'([A-Za-z0-9_]+)\)*/pallas_call"', text))
+    assert found == want, found
+    kernel_scopes = re.findall(r'op_name="([^"]*?([A-Za-z0-9_]+)\)*/pallas_call)"', text)
+    for k in scan:
+        ops = [s for s, name in kernel_scopes if name == k]
+        assert ops and all("mixer.mamba" in s for s in ops), (k, ops)
+    assert any("transpose(" in s and "mixer.mamba" in s for s, _ in kernel_scopes)
 
 
 @pytest.fixture
