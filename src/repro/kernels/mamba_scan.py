@@ -38,7 +38,8 @@ replays the forward recurrence.  The backward is two kernels:
 
 The log-decay gradient follows from the telescoping identity
   dL/dcum_t = q_t . dq_t - k_t . dk_t  (+ <dH_f, H_f> at the last position),
-so ``dla`` is one reverse cumsum over the full sequence outside the kernel.
+so ``dla`` is a reverse sum over the sequence outside the kernel: without
+resets one reverse cumsum.
 
 Segment ``reset`` rows (the §3.5 state-carry boundary — the scan analogue
 of ``row_task = -1`` gating) use EXACT masks, never a -1e9 log-decay
@@ -50,8 +51,12 @@ gated on the within-chunk reset count: intra-chunk pairs must share it,
 the inter-chunk/carry terms survive only when it is zero, and the
 chunk-exit weights only for the final sub-segment.  In the backward the
 same gates make pre-reset dq/dk/dv EXACTLY zero under a post-reset loss,
-and ``dla`` becomes a segment-bounded reverse cumsum (reverse cumsum minus
-its value at the next segment start).
+and ``dla`` becomes a segment-bounded reverse sum, computed chunked like the
+scan with no gather or scatter: within each chunk one matmul against a
+[Q, Q] same-segment mask built from the reset rows (shared by all heads),
+and across chunks each chunk's entry sum (positions before its first reset)
+carried back through an [n, n] reach mask onto the exit positions (after
+the last reset) of every earlier chunk that no reset cuts off.
 """
 from __future__ import annotations
 
@@ -329,23 +334,44 @@ def _fwd_call(q, k, v, la, li, r, h0, chunk, interpret, masked, save_states):
     )(q, k, v, _col(la), _col(li), _col(r.astype(jnp.float32)), h0)
 
 
-def _seg_rev_cumsum(dcum, r, masked):
+def _seg_rev_cumsum(dcum, r, chunk, masked):
     """dla_t = sum_{i>=t, same segment} dC_i over the last axis of
-    ``dcum`` [B, H, S]: the plain reverse cumsum minus its value at the
-    NEXT segment's start (gathered via the global segment index) — exactly
-    bounded, no sentinel arithmetic."""
-    rev = jnp.flip(jnp.cumsum(jnp.flip(dcum, axis=2), axis=2), axis=2)
+    ``dcum`` [B, H, S], with segments started by the ``r`` [B, S] rows.
+
+    Chunked like the scan, gather- and scatter-free: within a chunk a
+    [Q, Q] same-segment, i >= t mask (one per batch row and chunk, shared
+    by all heads); across chunks each chunk's ENTRY sum (its positions
+    before its first reset) flows back through a [n, n] reach mask to the
+    earlier chunks no reset separates it from, and lands on their EXIT
+    positions (after their last reset).  Masks are exact 0/1, so a term
+    outside the segment contributes an exact zero."""
     if not masked:
-        return rev
+        return jnp.flip(jnp.cumsum(jnp.flip(dcum, axis=2), axis=2), axis=2)
     B, H, S = dcum.shape
-    seg = jnp.cumsum(r, axis=1)[:, None, :]  # [B, 1, S] global segment index
-    bidx = jnp.arange(B)[:, None, None]
-    hidx = jnp.arange(H)[None, :, None]
-    # rev at each segment's first (reset) position, scattered by segment id
-    starts = jnp.zeros((B, H, S + 2), dcum.dtype).at[
-        bidx, hidx, jnp.where(r[:, None, :] > 0, seg, S + 1)
-    ].add(rev * (r[:, None, :] > 0).astype(dcum.dtype))
-    return rev - starts[bidx, hidx, jnp.minimum(seg + 1, S + 1)]
+    Q = chunk
+    n = S // Q
+    hi = jax.lax.Precision.HIGHEST  # the TPU's default f32 dot is one bf16 pass
+    d = dcum.reshape(B, H, n, Q)
+    rc = r.reshape(B, n, Q)
+    seg = jnp.cumsum(rc, axis=2)                   # in-chunk inclusive count
+    n_res = seg[:, :, -1]                          # [B, n] resets per chunk
+    pos = jnp.arange(Q)
+    # intra[t] = sum_{i >= t, seg_i == seg_t} d_i, within the chunk
+    pair = ((pos[None, :] >= pos[:, None])
+            & (seg[:, :, :, None] == seg[:, :, None, :])).astype(d.dtype)
+    intra = jnp.einsum("bhci,bcti->bhct", d, pair, precision=hi)
+    # a chunk's entry sum reaches chunk c' < c when no reset lies in c'+1..c-1
+    esum = jnp.einsum("bhci,bci->bhc", d, (seg == 0).astype(d.dtype),
+                      precision=hi)
+    seen = jnp.cumsum(n_res, axis=1)               # resets in chunks 0..c
+    cidx = jnp.arange(n)
+    reach = ((cidx[None, :] > cidx[:, None])
+             & ((seen - n_res)[:, None, :] == seen[:, :, None])
+             ).astype(d.dtype)                     # [B, c', c]
+    carry = jnp.einsum("bhc,bkc->bhk", esum, reach, precision=hi)
+    exit_ = (seg == n_res[:, :, None]).astype(d.dtype)  # [B, n, Q]
+    dla = intra + exit_[:, None] * carry[..., None]
+    return dla.reshape(B, H, S)
 
 
 def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
@@ -420,7 +446,7 @@ def _bwd_call(q, k, v, la, li, r, hin, hfin, dy, dhf, chunk, interpret,
     # segment's positions see it) before the segment-bounded reverse cumsum.
     dcum = dcum[..., 0].at[:, :, -1].add(
         jnp.einsum("bhkv,bhkv->bh", dhf, hfin))
-    dla = _seg_rev_cumsum(dcum, r, masked)
+    dla = _seg_rev_cumsum(dcum, r, Q, masked)
     d_r = np.zeros(r.shape, jax.dtypes.float0)
     return dq, dkk, dvv, dla, dli[..., 0], d_r, dh0
 

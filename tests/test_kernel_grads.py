@@ -492,6 +492,64 @@ def test_mamba_scan_reset_matches_segmented_oracle(key):
         )
 
 
+def _seg_rev_sum_loop(dcum, reset):
+    """float64 loop: dla_t = sum of dcum_i over i >= t in t's segment."""
+    dcum = np.asarray(dcum, np.float64)
+    out = np.zeros_like(dcum)
+    for b in range(dcum.shape[0]):
+        acc = np.zeros(dcum.shape[1])
+        for t in reversed(range(dcum.shape[2])):
+            acc = acc + dcum[b, :, t]
+            out[b, :, t] = acc
+            if reset[b, t]:
+                acc = np.zeros_like(acc)
+    return out
+
+
+_RESET_PATTERNS = {
+    "start": lambda Q, S: [0],              # the training cells' own case
+    "mid_chunk": lambda Q, S: [Q + 5],
+    "chunk_boundary": lambda Q, S: [Q],
+    "consecutive": lambda Q, S: [Q + 3, Q + 4],
+    "last": lambda Q, S: [S - 1],
+    "several_in_chunk": lambda Q, S: [Q + 1, Q + 4, Q + 9, Q + 10],
+    "quiet_chunk_between": lambda Q, S: [3, 2 * Q + 5],
+    "none": lambda Q, S: [],
+}
+
+
+@pytest.mark.parametrize("Q", [16, 32])
+@pytest.mark.parametrize("pattern", list(_RESET_PATTERNS))
+def test_mamba_scan_segmented_reverse_sum_matches_loop(pattern, Q):
+    """The scan backward's chunked, segment-bounded reverse sum (``dla``)
+    against a float64 loop, on reset patterns that cut chunks anywhere;
+    the second row has its own pattern.  A segment ending at a reset sees
+    nothing after it: with ``dcum`` zero before a row's last reset, ``dla``
+    is EXACTLY zero there."""
+    from repro.kernels.mamba_scan import _seg_rev_cumsum
+
+    B, H, S = 2, 3, 96
+    reset = np.zeros((B, S), np.int32)
+    reset[0, _RESET_PATTERNS[pattern](Q, S)] = 1
+    reset[1, [0, S // 2 + 1]] = 1
+    dcum = np.random.default_rng(Q).standard_normal((B, H, S)).astype(
+        np.float32)
+    dla = np.asarray(_seg_rev_cumsum(jnp.asarray(dcum), jnp.asarray(reset),
+                                     Q, True))
+    np.testing.assert_allclose(dla, _seg_rev_sum_loop(dcum, reset),
+                               rtol=1e-5, atol=1e-5)
+
+    for b in range(B):
+        last = int(np.flatnonzero(reset[b]).max(initial=0))
+        dcum[b, :, :last] = 0.0
+    dla = np.asarray(_seg_rev_cumsum(jnp.asarray(dcum), jnp.asarray(reset),
+                                     Q, True))
+    for b in range(B):
+        last = int(np.flatnonzero(reset[b]).max(initial=0))
+        np.testing.assert_array_equal(dla[b, :, :last], 0.0)
+        assert np.abs(dla[b, :, last:]).max() > 0
+
+
 # ---------------------------------------------------------------------------
 # ssm / hybrid cells: the scan backward inside the real model blocks
 # ---------------------------------------------------------------------------

@@ -194,21 +194,32 @@ def test_mamba_scan_compiles(one_chip, with_reset):
         "mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"))
 
 
-def test_granite_mamba_scan_compiles(one_chip):
+@pytest.mark.parametrize("with_reset", [False, True])
+def test_granite_mamba_scan_compiles(one_chip, with_reset):
     """Forward and both backward kernels at granite's widths: the state
-    tile is (128, 64) f32, the chunk (256, 128) / (256, 64)."""
+    tile is (128, 64) f32, the chunk (256, 128) / (256, 64).  The training
+    cell carries a reset row (1.0 at each row's first position), so the
+    backward takes the segment-bounded ``dla`` sum: it compiles to matmuls
+    against masks, with no gather or scatter around the kernels."""
     g = GRANITE
     B, S, H = g["B"], g["S"], g["H"]
     args = [_spec(one_chip, (B, S, H, g["dk"]))] * 2 + [
         _spec(one_chip, (B, S, H, g["dv"]))] + [
         _spec(one_chip, (B, S, H), jnp.float32)] * 2
+    if with_reset:
+        args.append(_spec(one_chip, (B, S), jnp.float32))
 
-    def fwd_bwd(q, k, v, la, li):
-        return _grads(lambda *a: mamba_scan_pallas(*a, chunk=g["chunk"]),
-                      (0, 1, 2, 3, 4))(q, k, v, la, li)
+    def fwd_bwd(q, k, v, la, li, *reset):
+        def scan(q, k, v, la, li):
+            return mamba_scan_pallas(q, k, v, la, li, chunk=g["chunk"],
+                                     reset=reset[0] if reset else None)
 
-    _compile_for_chip(fwd_bwd, *args, names=(
-        "mamba_scan_fwd", "mamba_scan_bwd_state", "mamba_scan_bwd_chunk"))
+        return _grads(scan, (0, 1, 2, 3, 4))(q, k, v, la, li)
+
+    text = _compile_for_chip(fwd_bwd, *args, names=(
+        "mamba_scan_fwd", "mamba_scan_bwd_state",
+        "mamba_scan_bwd_chunk")).as_text()
+    assert not re.findall(r"\b(?:gather|scatter)\(", text)
 
 
 def test_granite_attention_compiles_with_its_scale(one_chip, pallas_tier):
