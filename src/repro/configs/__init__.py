@@ -8,8 +8,8 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from dataclasses import dataclass
+from typing import Tuple
 
 import numpy as np
 
@@ -25,11 +25,9 @@ CONV_W = 4
 class ShapeSpec:
     """One assigned input shape.
 
-    ``kind`` selects which step is lowered for the dry-run:
-      * ``train``   -> ``train_step`` (fwd + adapter-grad bwd + optimizer)
-      * ``prefill`` -> ``prefill_step`` (forward, logits, no bwd)
-      * ``decode``  -> ``serve_step``  (one new token over a KV cache of
-                        ``seq_len``)
+    ``kind`` (train | prefill | decode) selects the layout that
+    ``launch.rules.rules_for`` gives the shape; a ``decode`` shape is one
+    new token over a KV cache of ``seq_len``.
     """
 
     name: str
@@ -57,7 +55,7 @@ class ArchConfig:
 
     ``family`` in {dense, moe, hybrid, ssm, vlm, audio}.  The model zoo
     (``repro.models``) assembles blocks from these fields; the same config
-    object also drives sharding rules and the dry-run input specs.
+    object also drives the sharding rules.
     """
 
     name: str
@@ -126,7 +124,6 @@ class ArchConfig:
     backbone_dtype: str = "bfloat16"
     remat: bool = True
     scan_layers: bool = True
-    use_pallas: bool = False  # TPU target path; CPU dry-run uses jnp flash
     attn_q_block: int = 512
     attn_kv_block: int = 1024
 
@@ -286,13 +283,3 @@ def smoke_config(name: str) -> ArchConfig:
     if cfg.family == "vlm":
         over.update(mrope_sections=(2, 3, 3))  # sums to head_dim/2 = 8
     return cfg.with_overrides(**over)
-
-
-def dryrun_cells(arch: str) -> list[str]:
-    """Which shapes the dry-run exercises for this arch (per DESIGN.md)."""
-    cfg = get_config(arch)
-    cells = ["train_4k", "prefill_32k", "decode_32k"]
-    # long_500k requires sub-quadratic attention: SSM / hybrid only.
-    if cfg.family in ("ssm", "hybrid"):
-        cells.append("long_500k")
-    return cells

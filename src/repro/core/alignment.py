@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -168,7 +168,3 @@ def align_tasks(
             inter += L - row.used()  # row-remainder chunks: inter-task waste
         rows.extend(open_rows)
     return AlignmentPlan(mode, chunk, L, rows, eff, intra, inter)
-
-
-def htask_token_count(plan: AlignmentPlan) -> int:
-    return plan.total_tokens

@@ -15,8 +15,7 @@ Both are shard_map-compatible (explicit axis names) and pure-jax.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,21 +47,6 @@ def int8_psum(x: jax.Array, axis_name: str, block: int = 256) -> jax.Array:
     n_dev = jax.lax.psum(jnp.ones((), jnp.float32), axis_name)
     # each replica contributed q_i * scale_i; approximate with mean scale
     out = q_sum.astype(jnp.float32) * (scale_sum / n_dev)
-    return out.reshape(-1)[: flat.shape[0]].reshape(x.shape).astype(x.dtype)
-
-
-def exact_int8_psum(x: jax.Array, axis_name: str, block: int = 256) -> jax.Array:
-    """Exact variant: all-reduce q*scale pairs via two psums (int payload +
-    per-replica scale products).  Wire bytes: 1B/elem + 4B/block."""
-    flat = x.reshape(-1).astype(jnp.float32)
-    q, scale = quantize_int8(flat, block)
-    contrib = q.astype(jnp.float32) * scale       # dequantized local contribution
-    # pack: psum of per-block dequantized payload would be f32 again; instead
-    # psum int8 payload and scales separately — exact when scales are equal,
-    # bounded error otherwise (scales within a block differ across replicas).
-    q_sum = jax.lax.psum(q.astype(jnp.int32), axis_name)
-    s_max = jax.lax.pmax(scale, axis_name)
-    out = q_sum.astype(jnp.float32) * s_max
     return out.reshape(-1)[: flat.shape[0]].reshape(x.shape).astype(x.dtype)
 
 

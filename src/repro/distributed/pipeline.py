@@ -12,17 +12,14 @@ resulting schedule matches the paper's symmetric-1F1B timing model, and the
 not code.
 
 ``pipeline_reference`` runs the same clock loop without shard_map (single
-device) for semantics tests; the shard_map path is exercised by the
-dry-run at mesh scale.
+device) for semantics tests.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -131,20 +128,3 @@ def pipeline_shard_map(
         out_specs=P(),
         check_vma=False,
     )(stage_params, microbatches)
-
-
-def pipeline_loss(
-    stage_fn: Callable[[Any, jax.Array], jax.Array],
-    loss_fn: Callable[[jax.Array], jax.Array],
-    stage_params: Any,
-    microbatches: jax.Array,
-    n_stages: int,
-    mesh: Optional[Mesh] = None,
-    stage_axis: str = "stage",
-) -> jax.Array:
-    """End-to-end pipelined loss (AD through it = backward pipeline)."""
-    if mesh is not None and stage_axis in mesh.axis_names and mesh.shape[stage_axis] > 1:
-        outs = pipeline_shard_map(stage_fn, stage_params, microbatches, mesh, stage_axis)
-    else:
-        outs = pipeline_reference(stage_fn, stage_params, microbatches, n_stages)
-    return loss_fn(outs)

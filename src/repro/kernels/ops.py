@@ -2,8 +2,7 @@
 
 Each op has three execution paths:
   * ``xla``     — pure-jnp formulation (gather-einsum / flash-scan) that XLA
-                  compiles well and GSPMD shards; default on CPU and in the
-                  512-device dry-run.
+                  compiles well and GSPMD shards; default on CPU.
   * ``pallas``  — the compiled ``pl.pallas_call`` kernel (BlockSpec VMEM
                   tiling); the default whenever the backend is a TPU.
   * ``pallas_interpret`` — the same kernel body executed in interpret mode;
@@ -60,7 +59,6 @@ before building/compiling steps, not between calls of a compiled step.
 """
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Optional
 

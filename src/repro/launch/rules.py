@@ -1,6 +1,6 @@
 """Per-(architecture x shape) sharding-rule selection — DESIGN.md §5.
 
-The baseline layout the dry-run lowers:
+The baseline layout:
  * train/prefill: batch over ("pod","data"); sequence-sharded residual (SP)
    over "model"; MLP/vocab/experts TP over "model"; attention head-sharded
    ("pairs" flash) when num_heads % tp == 0, else context-parallel q-seq
@@ -9,15 +9,11 @@ The baseline layout the dry-run lowers:
    KV cache sharded on head_dim over "model" when divisible (keeps the
    cache-append dynamic-update local), else on kv_heads, else on cache_seq;
    long-context (batch=1) shards cache_seq over ("pod","data").
-
-Overrides for the §Perf hillclimb enter through ``overrides`` so the
-iteration log can name each change precisely.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
-import numpy as np
 from jax.sharding import Mesh
 
 from repro.configs import ArchConfig, ShapeSpec
@@ -26,10 +22,6 @@ from repro.distributed.sharding import ShardingRules
 
 def _axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape[name] if name in mesh.axis_names else 1
-
-
-def dp_size(mesh: Mesh) -> int:
-    return _axis_size(mesh, "pod") * _axis_size(mesh, "data")
 
 
 def tp_size(mesh: Mesh) -> int:
@@ -42,14 +34,9 @@ def attn_mode_for(cfg: ArchConfig, mesh: Mesh) -> str:
     return "pairs" if cfg.num_heads % tp_size(mesh) == 0 else "kvscan"
 
 
-def rules_for(
-    cfg: ArchConfig,
-    shape: ShapeSpec,
-    mesh: Mesh,
-    overrides: Optional[Dict[str, object]] = None,
-) -> ShardingRules:
+def rules_for(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh) -> ShardingRules:
     tp = tp_size(mesh)
-    dp = dp_size(mesh)
+    dp = _axis_size(mesh, "pod") * _axis_size(mesh, "data")
     r = ShardingRules()
 
     updates: Dict[str, object] = {}
@@ -89,12 +76,4 @@ def rules_for(
         )
     # vocab: padded to 256 so always divisible by tp<=16
     updates["vocab"] = "model"
-    if overrides:
-        updates.update(overrides)
     return r.with_updates(**updates)
-
-
-def cache_logical_axes(cfg: ArchConfig):
-    """Logical axes of the decode-state pytree leaves (for in_shardings)."""
-    kv = ("layers", "cache_batch", "cache_seq", "kv_heads", "head_dim")
-    return kv

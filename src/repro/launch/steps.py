@@ -1,100 +1,30 @@
-"""Step builders + abstract input specs for launcher, dry-run and benchmarks.
+"""The engine's decode step builders and the host-to-device prefetch.
 
-``input_specs`` returns weak-type-correct ``ShapeDtypeStruct`` stand-ins for
-every model input (tokens, labels, masks, mrope ids, audio frames, decode
-state) — shardable, zero allocation.  ``build_*_step`` return the exact
-callables the production system jits: the multi-task PEFT train step
-(adapter-grad backward + AdamW), the prefill step, and the serve step (one
-token over the KV/SSM state).
+``prefetch_to_device`` / ``device_put_batch`` keep the training loop's next
+batches in flight to the device.  The task-aware decode pool
+(``init_decode_pool``, sampling, ``build_decode_micro_step`` and the bind
+steps) is the data plane of co-served inference.  ``opt_shardings`` lays out
+AdamW moments over a mesh's data-parallel ranks.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs import ArchConfig, ShapeSpec
-from repro.distributed.sharding import ShardingRules, activate_rules, logical_to_spec
-from repro.models.layers import abstract, is_spec_leaf, spec_logical_axes
+from repro.configs import ArchConfig
+from repro.distributed.sharding import ShardingRules, logical_to_spec
 from repro.models.transformer import Model
 from repro.obs.tracing import span
-from repro.peft.adapters import LORA
-from repro.peft.methods import AdapterConfig
-from repro.peft.multitask import MultiTaskAdapters, TaskSegments
-from repro.train.optimizer import AdamWState, adamw_init, adamw_update, apply_updates
-
-# ---------------------------------------------------------------------------
-# Batch specs
-# ---------------------------------------------------------------------------
-
-BATCH_AXES: Dict[str, Tuple] = {
-    "tokens": ("batch", "seq"),
-    "labels": ("batch", "seq"),
-    "loss_mask": ("batch", "seq"),
-    "segment_ids": ("batch", "seq"),
-    "positions": ("batch", "seq"),
-    "reset": ("batch", "seq"),
-    "mrope_positions": (None, "batch", "seq"),
-    "audio_embed": ("batch", None, None),
-}
-
-
-def batch_specs(cfg: ArchConfig, shape: ShapeSpec, with_labels: bool = True,
-                with_positions: bool = False) -> Dict[str, jax.ShapeDtypeStruct]:
-    B, S = shape.global_batch, shape.seq_len
-    role = shape.kind
-    if role == "decode":
-        return {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32)}
-    specs = {
-        "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
-    }
-    if with_positions:
-        # striped-CP layout: global positions travel with the data
-        specs["positions"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
-    if with_labels and role == "train":
-        specs["labels"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        specs["loss_mask"] = jax.ShapeDtypeStruct((B, S), jnp.float32)
-    if cfg.mrope:
-        specs["mrope_positions"] = jax.ShapeDtypeStruct((3, B, S), jnp.int32)
-    if cfg.family == "audio":
-        specs["audio_embed"] = jax.ShapeDtypeStruct(
-            (B, cfg.max_source_positions, cfg.d_model), jnp.bfloat16
-        )
-    return specs
-
-
-def batch_shardings(specs: Dict[str, Any], mesh: Mesh, rules: ShardingRules):
-    r = rules.mesh_axes(mesh)
-    return {
-        k: NamedSharding(mesh, logical_to_spec(BATCH_AXES[k], r))
-        for k, v in specs.items()
-    }
+from repro.peft.multitask import MultiTaskAdapters
+from repro.train.optimizer import AdamWState
 
 
 # ---------------------------------------------------------------------------
-# Param / adapter / state shardings
+# Optimizer-state layout
 # ---------------------------------------------------------------------------
-
-
-def tree_shardings(axes_tree: Any, mesh: Mesh, rules: ShardingRules):
-    r = rules.mesh_axes(mesh)
-    return jax.tree.map(
-        lambda axes: NamedSharding(mesh, logical_to_spec(axes, r)),
-        axes_tree,
-        is_leaf=lambda x: isinstance(x, tuple) and all(a is None or isinstance(a, str) for a in x),
-    )
-
-
-def param_shardings(model: Model, mesh: Mesh, rules: ShardingRules):
-    return tree_shardings(spec_logical_axes(model.spec()), mesh, rules)
-
-
-def adapter_shardings(mta: MultiTaskAdapters, mesh: Mesh, rules: ShardingRules):
-    return tree_shardings(spec_logical_axes(mta.spec()), mesh, rules)
 
 
 def opt_shardings(opt_abstract: AdamWState, mesh: Mesh,
@@ -155,72 +85,6 @@ def opt_shardings(opt_abstract: AdamWState, mesh: Mesh,
     return AdamWState(rep, moments(opt_abstract.m), moments(opt_abstract.v))
 
 
-def _state_axes(cfg: ArchConfig, state: Any) -> Any:
-    """Logical axes tree matching a decode-state pytree."""
-
-    def walk(node, path):
-        if isinstance(node, dict):
-            return {k: walk(v, path + (k,)) for k, v in node.items()}
-        nd = node.ndim if hasattr(node, "ndim") else 0
-        if path[-1] in ("pos", "t", "lo") or nd == 0:
-            # per-row decode-pool counters shard with the cache batch
-            return ("cache_batch",)[:nd]
-        if path[0] == "kv" or path[-1] in ("cross_k", "cross_v"):
-            return ("layers", "cache_batch", "cache_seq", "kv_heads", "head_dim")[:nd] if path[0] == "kv" else (
-                "layers", "cache_batch", None, "heads", "head_dim")[:nd]
-        if path[0] == "mamba":
-            if path[-1] == "h":  # [ns, per, B, nh, st, hd]
-                return ("layers", "layers", "cache_batch", "ssm_heads", None, None)[:nd]
-            return ("layers", "layers", "cache_batch", None, "ssm_inner")[:nd]
-        if path[0] == "mlstm":  # [ns, per, B, nh, dk, dv]
-            return ("layers", "layers", "cache_batch", None, "ssm_state", None)[:nd]
-        if path[0] == "slstm":  # [ns, B, nh, hd]
-            return ("layers", "cache_batch", None, None)[:nd]
-        return tuple([None] * nd)
-
-    return walk(state, ())
-
-
-def decode_state_specs(model: Model, shape: ShapeSpec) -> Any:
-    """Abstract decode state via eval_shape (no allocation)."""
-    cfg = model.cfg
-
-    def init():
-        return model.init_decode_state(None, shape.global_batch, shape.seq_len)
-
-    return jax.eval_shape(init)
-
-
-def decode_state_shardings(model: Model, shape: ShapeSpec, mesh: Mesh, rules: ShardingRules):
-    state = decode_state_specs(model, shape)
-    axes = _state_axes(model.cfg, state)
-    r = rules.mesh_axes(mesh)
-    return jax.tree.map(
-        lambda a: NamedSharding(mesh, logical_to_spec(a, r)),
-        axes,
-        is_leaf=lambda x: isinstance(x, tuple),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Multi-task setup for production cells
-# ---------------------------------------------------------------------------
-
-
-def dryrun_tasks(cfg: ArchConfig, shape: ShapeSpec, n_tasks: int = 8, rank: int = 16):
-    """The multi-tenant task set a production train cell carries."""
-    n_tasks = min(n_tasks, shape.global_batch)
-    cfgs = [AdapterConfig(LORA, rank=rank) for _ in range(n_tasks)]
-    mta = MultiTaskAdapters(cfg, cfgs)
-    rows = shape.global_batch // n_tasks
-    seg = TaskSegments.contiguous([rows] * n_tasks)
-    # remainder rows go to the last task
-    if rows * n_tasks != shape.global_batch:
-        extra = shape.global_batch - rows * n_tasks
-        seg = TaskSegments(seg.row_task + (n_tasks - 1,) * extra, n_tasks)
-    return mta, seg
-
-
 # ---------------------------------------------------------------------------
 # Host→device transfer (stall-free dispatch discipline)
 # ---------------------------------------------------------------------------
@@ -270,51 +134,6 @@ def prefetch_to_device(it, size: int = 2, shardings: Optional[Dict] = None):
         out = buf.popleft()
         fill()
         yield out
-
-
-# ---------------------------------------------------------------------------
-# Steps
-# ---------------------------------------------------------------------------
-
-
-def build_train_step(model: Model, mta: MultiTaskAdapters, segments: TaskSegments,
-                     lr: float = 1e-4, aux_coef: float = 1e-3):
-    ctxf = mta.ctx_factory(segments)
-
-    def train_step(backbone, adapters, opt_state, batch):
-        # phase scopes as in ``PEFTEngine._make_step``
-        def loss_fn(ad):
-            with jax.named_scope("train.forward"):
-                out = model.forward(backbone, batch, adapters=ad, ctx_factory=ctxf)
-                pt = segments.per_task_loss(out["per_token_loss"], batch["loss_mask"])
-                loss = pt.sum()
-                for k, v in out["aux"].items():
-                    if k == "moe_load_balance":
-                        loss = loss + aux_coef * v
-            return loss, pt
-
-        (loss, pt), grads = jax.value_and_grad(loss_fn, has_aux=True, allow_int=True)(adapters)
-        with jax.named_scope("train.optimizer"):
-            updates, opt_state = adamw_update(grads, opt_state, adapters, lr=lr)
-            adapters = apply_updates(adapters, updates)
-        return adapters, opt_state, loss, pt
-
-    return train_step
-
-
-def build_prefill_step(model: Model):
-    def prefill_step(backbone, batch):
-        out = model.forward(backbone, batch, return_logits=True)
-        return out["logits"]
-
-    return prefill_step
-
-
-def build_serve_step(model: Model):
-    def serve_step(backbone, state, tokens):
-        return model.decode_step(backbone, state, tokens)
-
-    return serve_step
 
 
 # ---------------------------------------------------------------------------

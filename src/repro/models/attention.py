@@ -16,7 +16,6 @@ Both support segment ids (chunk-packed batches from §3.5 alignment).
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -187,9 +186,7 @@ def flash_attention_pairs(
         l = jax.lax.dynamic_update_index_in_dim(l, l_new, i, axis=1)
         return (o, m, l), None
 
-    from repro.models.flags import cost_unroll
-
-    (o, m, l), _ = jax.lax.scan(step, (o, m, l), pairs, unroll=cost_unroll())
+    (o, m, l), _ = jax.lax.scan(step, (o, m, l), pairs)
     out = o / jnp.maximum(l[..., None], 1e-20)
     return out.reshape(B, S, H, dh).astype(q.dtype)
 
@@ -258,9 +255,7 @@ def flash_attention_kvscan(
         o_new = o * alpha[..., None] + pv
         return (o_new, m_new, l_new), None
 
-    from repro.models.flags import cost_unroll
-
-    (o, m, l), _ = jax.lax.scan(step, (o, m, l), jnp.arange(n), unroll=cost_unroll())
+    (o, m, l), _ = jax.lax.scan(step, (o, m, l), jnp.arange(n))
     out = o / jnp.maximum(l[..., None], 1e-20)
     return out.reshape(B, S, H, dh).astype(q.dtype)
 

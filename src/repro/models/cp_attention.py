@@ -19,8 +19,7 @@ FLOPs: ~S^2/2 per head total (vs S^2 for kvscan), balanced across ranks.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -116,10 +115,7 @@ def _flash_ragged_pairs(
         l = jax.lax.dynamic_update_index_in_dim(l, l_new, i, axis=1)
         return (o, m, l), None
 
-    from repro.models.flags import cost_unroll
-
-    (o, m, l), _ = jax.lax.scan(step, (o, m, l), jnp.asarray(pairs),
-                                unroll=cost_unroll())
+    (o, m, l), _ = jax.lax.scan(step, (o, m, l), jnp.asarray(pairs))
     return (o / jnp.maximum(l[..., None], 1e-20))
 
 

@@ -1,14 +1,14 @@
 """Shared layer primitives: ParamSpec trees, norms, RoPE/M-RoPE, MLPs.
 
 Parameters are declared as ``ParamSpec`` trees so the same declaration
-drives (a) real initialization, (b) abstract ``ShapeDtypeStruct`` twins for
-the 512-device dry-run, and (c) ``NamedSharding`` derivation from logical
-axes — params are never materialized at production scale.
+drives (a) real initialization, (b) abstract ``ShapeDtypeStruct`` twins
+for compiling without allocating, and (c) ``NamedSharding`` derivation
+from logical axes.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,11 +57,6 @@ def abstract(spec_tree):
 
 def spec_logical_axes(spec_tree):
     return jax.tree.map(lambda s: s.axes, spec_tree, is_leaf=is_spec_leaf)
-
-
-def param_count_tree(spec_tree) -> int:
-    leaves = jax.tree.leaves(spec_tree, is_leaf=is_spec_leaf)
-    return int(sum(int(np.prod(s.shape)) for s in leaves))
 
 
 # ---------------------------------------------------------------------------
@@ -217,12 +212,3 @@ def unembed_apply(p: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
     if "unembed" in p:
         return jnp.einsum("bsd,dv->bsv", x, p["unembed"])
     return jnp.einsum("bsd,vd->bsv", x, p["tok"])
-
-
-def softmax_xent(logits: jax.Array, labels: jax.Array, mask: jax.Array) -> jax.Array:
-    """Stable CE over (possibly padded) vocab. labels: int32, mask: f32."""
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    ll = jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
-    losses = (lse - ll) * mask
-    return losses.sum() / jnp.maximum(mask.sum(), 1.0)

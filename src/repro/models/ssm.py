@@ -111,19 +111,13 @@ def chunked_gla(
         )
         return h_new, (y_intra + y_inter).astype(q.dtype)
 
-    from repro.models.flags import cost_unroll
-
     if rc is None:
         scan_step, xs = step, (qc, kc, vc, lac, lic)
     else:
         def scan_step(hprev, xs_r):
             return step(hprev, xs_r[:-1], ri=xs_r[-1])
         xs = (qc, kc, vc, lac, lic, rc)
-    # Cost-measurement unrolling is capped: beyond 32 chunks the HLO blowup
-    # makes CPU compiles intractable; the roofline builder adds the analytic
-    # (n_chunks - 1) x per-chunk GLA correction for those cells instead.
-    h_final, yc = jax.lax.scan(scan_step, h0, xs,
-                               unroll=cost_unroll() and n <= 32)
+    h_final, yc = jax.lax.scan(scan_step, h0, xs)
     y = jnp.moveaxis(yc, 0, 1).reshape(B, S, H, dv)
     return y, h_final
 

@@ -18,14 +18,12 @@ adapter-only gradients — the backbone is frozen by construction.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.configs import ArchConfig, ShapeSpec
+from repro.configs import ArchConfig
 from repro.distributed.sharding import shard
 from repro.models import attention as attn
 from repro.models import ssm
@@ -41,7 +39,6 @@ from repro.models.layers import (
     pad_vocab,
     rms_norm,
     sinusoidal_positions,
-    softmax_xent,
     spec_logical_axes,
     unembed_apply,
 )
@@ -80,8 +77,8 @@ def _slice_layer(tree: Any, i) -> Any:
 
 
 def _scan_or_loop(body, carry, xs, length: int, use_scan: bool):
-    """lax.scan when compact HLO is wanted; unrolled loop for cost
-    extrapolation (cost_analysis counts while bodies once) and smoke tests."""
+    """lax.scan when compact HLO is wanted; an unrolled loop for smoke
+    tests."""
     if use_scan:
         return jax.lax.scan(body, carry, xs)
     ys = []
@@ -591,7 +588,7 @@ class Model:
             if audio_embed is not None:
                 enc = self._encode_audio(params, audio_embed)
                 ck = jax.vmap(lambda lp: self._cross_kv(lp, enc))(params["layers"]["cross"])
-            else:  # abstract path: zeros cross-KV (dry-run shape stand-in)
+            else:  # no audio given: zeros cross-KV (a shape stand-in)
                 src = cfg.max_source_positions
                 ck = (
                     jnp.zeros((cfg.num_layers, batch, src, cfg.num_heads, dh), cache_dtype),

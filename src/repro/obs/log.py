@@ -1,9 +1,9 @@
-"""Structured, rate-limited logging for the serving / benchmark drivers.
+"""Structured, rate-limited logging for the serving drivers.
 
 One logger tree rooted at ``repro.obs`` replaces the bare ``print``
-diagnostics in ``serve/replay.py`` and ``benchmarks/run.py``.  Data outputs
-(CSV benchmark rows, JSON artifacts) stay on stdout / in files — this
-logger is for *status*: progress, warnings, error context.
+diagnostics in ``serve/replay.py``.  Data outputs (JSON artifacts) stay
+on stdout / in files — this logger is for *status*: progress, warnings,
+error context.
 
 Level comes from ``REPRO_LOG`` (``debug`` / ``info`` / ``warning`` /
 ``error``; default ``info``).  A token-bucket filter rate-limits repeated

@@ -1,7 +1,7 @@
 import os
 import sys
 
-# Tests must see ONE device (the dry-run alone forces 512).
+# Tests run on the CPU; a test that needs more devices starts its own process.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -54,8 +54,10 @@ def test_decode_matches_forward_logits(arch, key):
 
 def test_rules_matrix_cells_valid():
     """rules_for() yields a consistent spec for every (arch x shape) cell:
-    all rule targets reference real mesh axes (the dry-run's contract)."""
-    from repro.configs import ARCH_NAMES, SHAPES, dryrun_cells, get_config
+    all rule targets reference real mesh axes.  ``long_500k`` needs
+    sub-quadratic attention, so it is a cell of the ssm / hybrid families
+    only."""
+    from repro.configs import ARCH_NAMES, SHAPES, get_config
     from repro.launch.mesh import make_mesh
     from repro.launch.rules import attn_mode_for, rules_for
 
@@ -63,8 +65,9 @@ def test_rules_matrix_cells_valid():
     seen = 0
     for arch in ARCH_NAMES:
         cfg = get_config(arch)
-        for shp in dryrun_cells(arch):
-            shape = SHAPES[shp]
+        for shp, shape in SHAPES.items():
+            if shp == "long_500k" and cfg.family not in ("ssm", "hybrid"):
+                continue
             r = rules_for(cfg, shape, mesh)
             mode = attn_mode_for(cfg, mesh)
             assert mode in ("pairs", "kvscan")

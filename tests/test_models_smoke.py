@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCH_NAMES, dryrun_cells, get_config, smoke_config
+from repro.configs import ARCH_NAMES, get_config, smoke_config
 from repro.models.transformer import build_model
 from repro.peft.adapters import LORA
 from repro.peft.methods import AdapterConfig
@@ -71,18 +71,6 @@ def test_arch_train_step_with_adapters(arch, key):
         if hasattr(g, "dtype") and g.dtype != jax.dtypes.float0
     )
     assert np.isfinite(gnorm) and gnorm > 0, f"{arch}: no adapter gradient signal"
-
-
-def test_dryrun_cell_assignment():
-    """long_500k only for sub-quadratic archs; every arch has >= 3 cells."""
-    for arch in ARCH_NAMES:
-        cells = dryrun_cells(arch)
-        assert len(cells) >= 3
-        cfg = get_config(arch)
-        if "long_500k" in cells:
-            assert cfg.family in ("ssm", "hybrid")
-        else:
-            assert cfg.family not in ("ssm", "hybrid")
 
 
 def test_param_counts_match_configs():
